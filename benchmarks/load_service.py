@@ -17,6 +17,11 @@ accrues queueing delay instead of silently slowing the generator
     PYTHONPATH=src python -m benchmarks.load_service --qps 50 \
         --duration 20 [--snapshot-dir DIR] [--snapshot-mid]
 
+The service process runs on whatever platform this one is given
+(``JAX_PLATFORMS`` is inherited); its backend is qwen3-1.7b at smoke
+width (``SERVICE_ARCH``), so the harness measures the cache service.
+On an accelerator the service must be the only process that holds it.
+
 ``--smoke`` is the CI gate (scripts/ci.sh): a short burst against a
 snapshotting service, a mid-run snapshot, a clean shutdown, then a
 restart from the snapshot that must come back warm (restored clock
@@ -55,17 +60,29 @@ class _Pending:
         self.done = threading.Event()
 
 
+def _check_chip_free() -> None:
+    """A chip belongs to one process: a parent that already runs jax on
+    an accelerator would leave the service child none."""
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized():
+        import jax
+        if jax.default_backend() != "cpu":
+            raise RuntimeError(
+                "this process holds the accelerator; start the load "
+                "harness from a process that has not run jax")
+
+
 class ServeClient:
     """Client for the ``--serve-stdio`` JSON-lines protocol: spawns the
     service, tags every message with an id, and matches replies on a
     reader thread (receive-timestamping them for latency accounting)."""
 
     def __init__(self, extra_args=(), env_extra=None, start_timeout=300.0):
+        _check_chip_free()
         env = dict(os.environ,
                    PYTHONPATH=SRC + (os.pathsep + os.environ["PYTHONPATH"]
                                      if os.environ.get("PYTHONPATH")
                                      else ""))
-        env.setdefault("JAX_PLATFORMS", "cpu")
         env.update(env_extra or {})
         self.proc = subprocess.Popen(
             [sys.executable, "-m", "repro.launch.serve", "--serve-stdio",
@@ -330,8 +347,12 @@ def restore_bench(n_rows: int = 262_144, d: int = 64,
 # entry points
 # ---------------------------------------------------------------------------
 
+SERVICE_ARCH = "qwen3-1.7b-smoke"
+
+
 def _service_args(snap_dir, capacity=512):
-    return ["--snapshot-dir", snap_dir, "--capacity", str(capacity)]
+    return ["--snapshot-dir", snap_dir, "--capacity", str(capacity),
+            "--arch", SERVICE_ARCH]
 
 
 def smoke() -> None:

@@ -9,7 +9,10 @@ Registered modules (see each module's docstring for what it reproduces):
 ``fused_serve``, ``l1_freshness``, ``adaptive_thresholds``.
 
 Prints ``name,us_per_call,derived`` CSV rows (derived = remaining fields
-as compact JSON) and writes results/benchmarks.json.
+as compact JSON) and writes results/benchmarks.json. A module that
+raises gets an ``<module>/ERROR`` row and the harness exits non-zero.
+With ``JAX_PLATFORMS=cpu`` the CPU backend is given 8 host devices, the
+mesh ``sharded_serve`` sweeps.
 """
 from __future__ import annotations
 
@@ -28,6 +31,8 @@ def main() -> None:
                     help="comma-separated module names")
     args = ap.parse_args()
 
+    from repro.launch.jax_setup import force_cpu_devices
+    force_cpu_devices(8)
     from benchmarks import (adaptive_thresholds, ann_index, dyn_index,
                             fig2, fused_serve, greyzone_roi,
                             kernels_bench, l1_freshness, latency_async,
@@ -59,11 +64,13 @@ def main() -> None:
 
     print("name,us_per_call,derived")
     all_rows = []
+    failed = []
     for mod_name, mod in modules.items():
         t0 = time.time()
         try:
             rows = mod.run(scale=args.scale)
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — reported, exits 1 below
+            failed.append(mod_name)
             rows = [{"name": f"{mod_name}/ERROR", "us_per_call": -1,
                      "error": str(e)[:300]}]
         for r in rows:
@@ -74,6 +81,8 @@ def main() -> None:
         all_rows.extend(rows)
 
     (RESULTS / "benchmarks.json").write_text(json.dumps(all_rows, indent=1))
+    if failed:
+        raise SystemExit(f"benchmarks failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -23,24 +23,20 @@ Two claims are measured:
 ``--smoke`` is the CI entry (scripts/ci.sh): a full serving-path
 differential — ``BaselinePolicy``/``KritesPolicy`` with ``mesh=`` vs
 single-device on the same trace, scalar and batched — asserting
-decision agreement 1.0. Registered in ``benchmarks.run``; when the
-parent process holds only one device (the harness), the sweep re-execs
-itself in a subprocess with a forced 8-device host platform.
+decision agreement 1.0. Both need 8 devices: run standalone with
+``JAX_PLATFORMS=cpu`` for an 8-device host mesh. Registered in
+``benchmarks.run``, which gives the CPU backend 8 devices itself.
 """
 from __future__ import annotations
 
+import argparse
+import json
 import os
+import subprocess
+import sys
+from pathlib import Path
 
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=8")
-
-import argparse      # noqa: E402
-import json          # noqa: E402
-import subprocess    # noqa: E402
-import sys           # noqa: E402
-from pathlib import Path  # noqa: E402
-
-import numpy as np   # noqa: E402
+import numpy as np
 
 SHARDS = (1, 2, 4, 8)
 SIZES_SMALL = (65_536, 262_144)
@@ -91,20 +87,26 @@ def _bench(scale: str = "small"):
 
 
 def run(scale: str = "small"):
-    """Entry for ``benchmarks.run``. The harness process usually holds a
-    single CPU device (jax initialized long before this module), so the
-    sweep re-execs in a child with the forced host-device mesh."""
-    import jax
+    """Entry for ``benchmarks.run``: the sweep needs ``max(SHARDS)``
+    devices. With ``JAX_PLATFORMS=cpu`` in a process that has not
+    started jax yet, it re-execs itself in a child with a forced
+    host-device mesh; otherwise it runs here, on the devices this
+    process has (a child never competes with this process for a
+    chip)."""
+    from jax._src import xla_bridge
 
-    if len(jax.devices()) >= max(SHARDS):
+    if os.environ.get("JAX_PLATFORMS") != "cpu" \
+            or xla_bridge.backends_are_initialized():
+        import jax
+        if len(jax.devices()) < max(SHARDS):
+            raise RuntimeError(
+                f"sharded_serve needs {max(SHARDS)} devices, this "
+                f"process has {len(jax.devices())}")
         return _bench(scale)
     src = str(Path(__file__).resolve().parents[1] / "src")
     env = dict(os.environ,
                PYTHONPATH=src + os.pathsep + os.environ.get("PYTHONPATH",
-                                                            ""),
-               XLA_FLAGS=f"--xla_force_host_platform_device_count"
-                         f"={max(SHARDS)}",
-               JAX_PLATFORMS="cpu")
+                                                            ""))
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks.sharded_serve", "--json",
          "--scale", scale],
@@ -134,8 +136,8 @@ def smoke(n_shards: int = 8, n: int = 160) -> None:
     from repro.launch.mesh import make_shard_mesh
 
     assert len(jax.devices()) >= n_shards, \
-        (f"smoke needs {n_shards} devices — run standalone so the "
-         f"module-level XLA_FLAGS host-device override applies")
+        (f"smoke needs {n_shards} devices — run standalone with "
+         f"JAX_PLATFORMS=cpu for a host-device mesh")
     mesh = make_shard_mesh(n_shards)
     spec = dataclasses.replace(LMARENA_LIKE, n_requests=4000,
                                n_classes=120)
@@ -213,6 +215,8 @@ def smoke(n_shards: int = 8, n: int = 160) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.jax_setup import force_cpu_devices
+    force_cpu_devices(max(SHARDS))
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", choices=["small", "full"], default="small")
     ap.add_argument("--smoke", action="store_true",

@@ -14,6 +14,8 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 export PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH}
+# tier-1 CI runs on the CPU (the multi-device smokes get host devices)
+export JAX_PLATFORMS=cpu
 
 echo "== tier-1 tests (per-test timeout 300s) =="
 python -m pytest -x -q --timeout=300

@@ -58,8 +58,6 @@ def from_compiled(name: str, compiled, mesh, model_flops: float,
                   hlo_text: Optional[str] = None) -> Roofline:
     from repro.analysis.hlo_parse import collective_bytes
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):   # older jax returns [dict]
-        ca = ca[0]
     flops = float(ca.get("flops", 0.0))
     byt = float(ca.get("bytes accessed", 0.0))
     text = hlo_text if hlo_text is not None else compiled.as_text()

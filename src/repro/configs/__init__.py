@@ -70,8 +70,21 @@ def smoke_config(arch_id: str):
     raise TypeError(type(cfg))
 
 
+def lm_config(name: str) -> LMConfig:
+    """An LM config by name: a registered arch (``qwen3-1.7b``) at its
+    published widths, or ``<arch>-smoke`` for its reduced CPU variant
+    (:func:`smoke_config`). The name alone decides; nothing here looks
+    at the device."""
+    base = name[:-len("-smoke")] if name.endswith("-smoke") else name
+    cfg = smoke_config(base) if base != name else get_arch(name)
+    if not isinstance(cfg, LMConfig):
+        raise TypeError(f"{name!r} is not an LM arch")
+    return cfg
+
+
 __all__ = [
     "ARCHS", "get_arch", "get_shape", "all_cells", "smoke_config",
+    "lm_config",
     "LMConfig", "MoEConfig", "GNNConfig", "RecSysConfig", "ShapeSpec",
     "LM_SHAPES", "LM_SHAPES_SKIPPED", "GNN_SHAPES", "RECSYS_SHAPES",
     "shapes_for",

@@ -329,5 +329,12 @@ class VerifyAndPromotePool:
                 and time.monotonic() - t0 < timeout_s:
             time.sleep(0.01)
 
-    def stop(self):
+    def stop(self, timeout_s: float = 1.0):
+        """Stop the workers and the reaper and wait (bounded) for them
+        to exit: an idle worker leaves within its 0.1 s queue poll, and
+        a daemon thread still alive at interpreter exit can abort the
+        process while the jax runtime tears down."""
         self._stop.set()
+        for t in (*self._workers, self._reaper):
+            if t is not threading.current_thread():
+                t.join(timeout_s)

@@ -296,6 +296,23 @@ class BaselinePolicy:
             return self._sh_dyn_fn(dyn, q)
         return _masked_dyn_topk(dyn.emb, dyn.valid, q)
 
+    def lookup_batch(self, V: jax.Array):
+        """The two tier lookups :meth:`serve_batch` makes, on their own:
+        static and dynamic top-1 of a normalized (B, d) block through
+        the configured path (fused, injected indexes, sharded or flat),
+        against the current dynamic tier. Returns host arrays
+        ``(s_static, h_idx, s_dyn, j)`` and the tier they were taken
+        on — what a reference check compares with."""
+        with self.dyn_lock:
+            snap = self.dyn
+            if self.fused is not None:
+                out = T.serve_lookup_batch(self.static, snap, V,
+                                           self.fused)
+            else:
+                out = (*self._static_topk_batch(V),
+                       *self._dyn_topk(snap, V))
+            return jax.device_get(out), snap
+
     def _host_lru_slot(self) -> int:
         """Host twin of tiers._lru_slot over the mirrored metadata."""
         key = np.where(self._valid_np, self._last_used_np, -_BIG)
@@ -627,11 +644,10 @@ class BaselinePolicy:
         the snapshot argmax of a later row."""
         excl = np.zeros(self.cfg.capacity, bool)
         excl[list(exclude)] = True
-        sims = jnp.where(jnp.logical_and(snap.valid,
-                                         jnp.asarray(~excl)),
-                         snap.emb @ v, -jnp.inf)
-        j = int(jnp.argmax(sims))
-        return float(sims[j]), j
+        s, j = jax.device_get(_masked_dyn_topk(
+            snap.emb, jnp.logical_and(snap.valid, jnp.asarray(~excl)),
+            v[None]))
+        return float(s[0]), int(j[0])
 
     def serve_batch(self, prompts: Sequence[str],
                     metas: Optional[Sequence[Optional[dict]]] = None

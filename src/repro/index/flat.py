@@ -37,6 +37,16 @@ def topk_scores(queries: jax.Array, cand_vecs: jax.Array,
     return vals, jnp.take(cand_ids, idx)
 
 
+def row_dots(q: jax.Array, c: jax.Array) -> jax.Array:
+    """(B, d) x (N, d) -> (B, N) fp32 dot products as a per-row
+    multiply-reduce over d. Each score depends only on its own two rows
+    and d, never on N, so any row partition of ``c`` (the row-sharded
+    twin in ``index/sharded.py``) reproduces it bit for bit — a matmul's
+    accumulation order follows its shape on some backends — and it is
+    full fp32 on every backend, with no reduced-precision MXU pass."""
+    return jnp.sum(q[:, None, :] * c[None, :, :], axis=-1)
+
+
 def masked_cosine_topk(queries: jax.Array, corpus: jax.Array,
                        valid: jax.Array, k: int = 1,
                        corpus_normalized: bool = False):
@@ -46,12 +56,13 @@ def masked_cosine_topk(queries: jax.Array, corpus: jax.Array,
     mirrors :func:`cosine_topk`: the dynamic tier's rows are already
     L2-normalized on insert (`core/tiers.py`), so the serving hot path
     passes True and skips a full-corpus renormalization per lookup.
+    Scores come from :func:`row_dots`.
     """
     q = l2_normalize(queries.astype(jnp.float32))
     c = corpus.astype(jnp.float32)
     if not corpus_normalized:
         c = l2_normalize(c)
-    sims = q @ c.T
+    sims = row_dots(q, c)
     sims = jnp.where(valid[None, :], sims, -jnp.inf)
     return jax.lax.top_k(sims, k)
 

@@ -36,24 +36,10 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-try:                                   # jax >= 0.5: public API, `check_vma`
-    from jax import shard_map as _shard_map
-    _CHECK_KW = "check_vma"
-except ImportError:                    # jax 0.4.x: experimental, `check_rep`
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
-
-def shard_map(f, **kw):
-    """Version-portable shard_map: translates the replication-check kwarg
-    (`check_vma` on new jax, `check_rep` on 0.4.x)."""
-    if "check_vma" in kw and _CHECK_KW != "check_vma":
-        kw[_CHECK_KW] = kw.pop("check_vma")
-    return _shard_map(f, **kw)
-
-from repro.index.flat import l2_normalize
+from repro.index.flat import l2_normalize, row_dots
 from repro.kernels.simsearch.ops import cosine_topk
 
 
@@ -97,7 +83,8 @@ def sharded_masked_topk(queries: jax.Array, emb: jax.Array,
     queries (B, d) replicated; emb (C, d) and valid (C,) sharded over
     ``axis``. Returns (scores (B, k), global slot ids (B, k)). Scores
     are bit-identical to ``masked_cosine_topk(corpus_normalized=True)``
-    (the per-row dot product is over the unpartitioned d axis) and the
+    (both score through ``row_dots``, whose per-row reduce over the
+    unpartitioned d axis does not depend on the shard's size) and the
     stable merge keeps the lowest-slot tie rule, so serving decisions
     match the single-device masked scan exactly. Invalid rows score
     -inf; a fully-invalid tier returns (-inf, 0) on both paths.
@@ -107,7 +94,7 @@ def sharded_masked_topk(queries: jax.Array, emb: jax.Array,
     q = l2_normalize(queries.astype(jnp.float32))
 
     def local(q, e, m):
-        sims = q @ e.T                                   # (B, rows_per)
+        sims = row_dots(q, e)                            # (B, rows_per)
         sims = jnp.where(m[None, :], sims, -jnp.inf)
         vals, idx = jax.lax.top_k(sims, k)
         gidx = idx + jax.lax.axis_index(axis) * rows_per

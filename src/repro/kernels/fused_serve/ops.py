@@ -124,7 +124,9 @@ def dyn_rerank_exact(queries: jax.Array, dyn_emb: jax.Array,
     """
     safe = jnp.clip(cand_slots, 0, dyn_emb.shape[0] - 1)
     rows = jnp.take(dyn_emb, safe, axis=0)                # (B, Cd, d)
-    exact = jnp.einsum("bcd,bd->bc", rows.astype(jnp.float32), queries)
+    # fp32 multiply-reduce, as in ivf_scan.ops.rerank_exact
+    exact = jnp.sum(rows.astype(jnp.float32) * queries[:, None, :],
+                    axis=-1)
     exact = jnp.where(cand_slots < 0, -jnp.inf, exact)
     order = jnp.lexsort((cand_slots, -exact))[:, :1]
     s = jnp.take_along_axis(exact, order, axis=1)[:, 0]

@@ -17,6 +17,13 @@ the output ordering identical to the ``ref.py`` oracle's
 (score desc, global id asc); padding slots (row id -1) are masked to
 NEG and flushed back as id -1.
 
+Mosaic requires each block's last two dims to be divisible by (8, 128)
+or to equal the array's. The per-query and per-cluster rows therefore
+travel with a unit middle axis — queries ``(B, 1, d)``, scales and ids
+``(K, 1, cap)``, outputs ``(B, 1, C)`` — so every block's last two dims
+equal the array's; the wrapper adds and drops that axis, and its
+contract stays ``(B, d)`` in, ``(B, C)`` out.
+
 A (1, d) query block underuses the MXU's sublane dimension; batching
 queries that probe the same cluster (cluster-grouped dispatch) is the
 known follow-up — the layout and scalar-prefetch machinery here
@@ -43,15 +50,15 @@ def _kernel(cids_ref, q_ref, codes_ref, scales_ref, ids_ref,
         run_v[...] = jnp.full_like(run_v, NEG)
         run_i[...] = jnp.full_like(run_i, BIG_IDX)
 
-    q = q_ref[...].astype(jnp.float32)                       # (1, d)
+    q = q_ref[0].astype(jnp.float32)                         # (1, d)
     q = q * jax.lax.rsqrt(
         jnp.maximum(jnp.sum(q * q, -1, keepdims=True), 1e-18))
     c = codes_ref[0].astype(jnp.float32)                     # (cap, d)
     sims = jax.lax.dot_general(
         q, c, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)                  # (1, cap)
-    sims = sims * scales_ref[...]
-    ids = ids_ref[...]                                       # (1, cap)
+    sims = sims * scales_ref[0]
+    ids = ids_ref[0]                                         # (1, cap)
     sims = jnp.where(ids < 0, NEG, sims)
     mids = jnp.where(ids < 0, BIG_IDX, ids)
 
@@ -63,10 +70,10 @@ def _kernel(cids_ref, q_ref, codes_ref, scales_ref, ids_ref,
 
     @pl.when(p == nprobe - 1)
     def _done():
-        vals_ref[...] = run_v[...]
+        vals_ref[0] = run_v[...]
         # absent candidates (still NEG) flush as id -1, like the oracle;
         # no real cosine can reach NEG so the test is unambiguous
-        idx_ref[...] = jnp.where(run_v[...] == NEG, -1, run_i[...])
+        idx_ref[0] = jnp.where(run_v[...] == NEG, -1, run_i[...])
 
 
 @functools.partial(jax.jit,
@@ -90,15 +97,17 @@ def ivf_scan_kernel(queries: jax.Array, cids: jax.Array,
         num_scalar_prefetch=1,
         grid=(B, nprobe),
         in_specs=[
-            pl.BlockSpec((1, d), lambda b, p, cids: (b, 0)),
+            pl.BlockSpec((1, 1, d), lambda b, p, cids: (b, 0, 0)),
             pl.BlockSpec((1, cap, d),
                          lambda b, p, cids: (cids[b, p], 0, 0)),
-            pl.BlockSpec((1, cap), lambda b, p, cids: (cids[b, p], 0)),
-            pl.BlockSpec((1, cap), lambda b, p, cids: (cids[b, p], 0)),
+            pl.BlockSpec((1, 1, cap),
+                         lambda b, p, cids: (cids[b, p], 0, 0)),
+            pl.BlockSpec((1, 1, cap),
+                         lambda b, p, cids: (cids[b, p], 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, C), lambda b, p, cids: (b, 0)),
-            pl.BlockSpec((1, C), lambda b, p, cids: (b, 0)),
+            pl.BlockSpec((1, 1, C), lambda b, p, cids: (b, 0, 0)),
+            pl.BlockSpec((1, 1, C), lambda b, p, cids: (b, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, C), jnp.float32),
@@ -109,9 +118,10 @@ def ivf_scan_kernel(queries: jax.Array, cids: jax.Array,
         kern,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, C), jnp.float32),
-            jax.ShapeDtypeStruct((B, C), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, C), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, C), jnp.int32),
         ],
         interpret=interpret,
-    )(cids.astype(jnp.int32), queries, codes, scales, row_ids)
-    return vals, idx
+    )(cids.astype(jnp.int32), queries[:, None, :], codes,
+      scales[:, None, :], row_ids[:, None, :])
+    return vals[:, 0], idx[:, 0]

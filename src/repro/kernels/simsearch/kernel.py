@@ -57,8 +57,12 @@ def _kernel(q_ref, c_ref, vals_ref, idx_ref, run_v, run_i, *, k, tile_n,
         jnp.maximum(jnp.sum(q * q, -1, keepdims=True), 1e-18))
     cn = c * jax.lax.rsqrt(
         jnp.maximum(jnp.sum(c * c, -1, keepdims=True), 1e-18))
+    # HIGHEST: at the default precision the MXU takes one bf16 pass
+    # and a served score is off by ~1e-3 (measured on a v5e), which
+    # moves threshold decisions; these scores are the exact ones
     sims = jax.lax.dot_general(
         qn, cn, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)                      # (B, tile)
 
     gidx = t * tile_n + jax.lax.broadcasted_iota(

@@ -16,9 +16,11 @@ bulk grey-zone verification) -> batched backend (DESIGN.md §7):
 
     PYTHONPATH=src python -m repro.launch.cache_workload --live
 """
-import os
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=512")
+from repro.launch.jax_setup import force_cpu_devices
+
+# the dry-run lowers against a fake 512-device mesh: with
+# JAX_PLATFORMS=cpu the CPU backend provides it (before jax starts)
+force_cpu_devices(512)
 
 import json                      # noqa: E402
 import time                      # noqa: E402
@@ -49,8 +51,6 @@ def run(B: int = 4096, S: int = 4_194_304, d: int = 64, k: int = 4,
         ).lower(q, corpus).compile()
     hlo = c.as_text()
     ca = c.cost_analysis()
-    if isinstance(ca, list):
-        ca = ca[0]
     mem = rl.memory_summary(c)
     args_b = mem.get("argument_size_in_bytes", 0.0)
     out_b = mem.get("output_size_in_bytes", 0.0)

@@ -1,5 +1,8 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+from repro.launch.jax_setup import force_cpu_devices
+
+# lowers against fake 512-device meshes, which the CPU backend provides
+# with JAX_PLATFORMS=cpu (set before jax starts)
+force_cpu_devices(512)
 
 # --- everything below may import jax -------------------------------------
 import argparse        # noqa: E402
@@ -29,8 +32,6 @@ def _compile_costs(wl, mesh) -> dict:
     compiled = lowered.compile()
     hlo = compiled.as_text()
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):
-        ca = ca[0]
     mem = rl.memory_summary(compiled)
     return {
         "flops": float(ca.get("flops", 0.0)),

@@ -1,13 +1,24 @@
 """Production mesh builders.
 
 Defined as FUNCTIONS (never module-level constants) so importing this
-module never touches jax device state. The dry-run sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
-import; smoke tests and benches see the 1 real CPU device.
+module never touches jax device state. With ``JAX_PLATFORMS=cpu`` the
+dry-run forces 512 host devices (``launch/jax_setup.py``) before jax
+starts; smoke tests and benches see the 1 real CPU device.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules
+    here are GSPMD-style hints (``with_sharding_constraint``,
+    ``shard_map`` specs) that assume the compiler may propagate
+    shardings, which Explicit axes — ``jax.make_mesh``'s default —
+    refuse."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,7 +30,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_shard_mesh(n_shards: int):
@@ -28,15 +39,15 @@ def make_shard_mesh(n_shards: int):
     lookup/write runs shard-local with a tiny candidate merge. On CPU
     pair with ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
     (set before the first jax import) — the launchers' ``--shards N``
-    flag does exactly that."""
-    return jax.make_mesh((n_shards,), ("model",))
+    flag does exactly that when ``JAX_PLATFORMS=cpu``."""
+    return make_mesh((n_shards,), ("model",))
 
 
 def make_smoke_mesh(n_devices: int | None = None):
     """Tiny mesh over whatever devices exist (tests / examples)."""
     n = n_devices or len(jax.devices())
     model = 2 if n % 2 == 0 else 1
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def dp_axes(mesh) -> tuple:

@@ -4,7 +4,11 @@
 
 Wires the full production topology on local devices: embedder -> tiered
 cache (KritesPolicy, async judge pool) -> batching frontend -> LLM engine
-(prefill + KV decode). ``--index ivf`` (with ``--static-rows N`` to pad
+(prefill + KV decode). ``--arch`` names the engine's config, built at
+its published widths (default qwen3-1.7b) with weights drawn from
+``--seed``; ``--arch qwen3-1.7b-smoke`` is the 2-layer x 64 variant for
+a CPU. :func:`build_service` does the wiring, for this launcher and for
+``chip_smoke.py``. ``--index ivf`` (with ``--static-rows N`` to pad
 the curated tier to a realistic size) swaps the static lookup for the
 IVF quantized ANN index (DESIGN.md §11):
 
@@ -13,9 +17,10 @@ IVF quantized ANN index (DESIGN.md §11):
 
 ``--shards N`` serves through the mesh-aware path (DESIGN.md §13): both
 tiers row-sharded over an N-device 'model' mesh, per-shard fused scans
-with a tiny candidate merge, writes scattered to the owning shard. On a
-CPU host it forces ``XLA_FLAGS=--xla_force_host_platform_device_count``
-so N host devices exist; decisions are identical to ``--shards 1``:
+with a tiny candidate merge, writes scattered to the owning shard. With
+``JAX_PLATFORMS=cpu`` it forces
+``XLA_FLAGS=--xla_force_host_platform_device_count`` so N host devices
+exist; decisions are identical to ``--shards 1``:
 
     PYTHONPATH=src python -m repro.launch.serve --requests 200 --shards 4
 
@@ -54,16 +59,20 @@ import os
 import sys
 import time
 
+from repro.launch.jax_setup import enable_compile_cache, force_cpu_devices
+
 
 def build_demo_tier(emb_rows, answers, static_rows: int = 0,
                     index: str = "flat", nprobe: int = 8, mesh=None,
-                    texts=None):
+                    texts=None, ivf=None):
     """Shared demo-topology helper (also used by
     ``launch/cache_workload.py --live``): optionally pad the curated
     tier with synthetic entries to ``static_rows`` rows, then build the
     requested static-index object (DESIGN.md §11) — the sharded variant
     (§13) when a ``mesh`` is given. ``texts`` are the curated entries'
-    prompt texts (row-aligned; judge payloads carry them).
+    prompt texts (row-aligned; judge payloads carry them). ``ivf`` is
+    an IVF already packed over this same tier, reused instead of a
+    rebuild (single-device ``index='ivf'`` only).
 
     Returns (StaticTier, answers, texts, index object or None for
     exact flat).
@@ -93,9 +102,9 @@ def build_demo_tier(emb_rows, answers, static_rows: int = 0,
             idx_obj = ShardedIVFIndex(tier.emb, mesh, nprobe=nprobe)
         else:
             from repro.index.ivf import IVFIndex, build_ivf
-            idx_obj = IVFIndex(build_ivf(tier.emb,
-                                         corpus_normalized=True),
-                               nprobe=nprobe)
+            if ivf is None:
+                ivf = build_ivf(tier.emb, corpus_normalized=True)
+            idx_obj = IVFIndex(ivf, nprobe=nprobe)
         print(f"static index: {idx_obj.describe()}")
     return tier, answers, texts, idx_obj
 
@@ -119,6 +128,19 @@ DEMO_INTENTS = [f"how do i {v} my {n}" for v in
                 ("fix", "update", "reset", "clean", "sell")
                 for n in ("bike", "laptop", "router", "garden")]
 DEMO_PREFIXES = ["", "hey ", "um, ", "please, ", "quick q: "]
+
+
+def demo_requests(n: int, seed: int = 0):
+    """``n`` demo (prompt, intent class) pairs: a random intent behind a
+    random filler prefix, so most requests paraphrase a curated one."""
+    import numpy as np
+    rng = np.random.default_rng(seed)
+    out = []
+    for _ in range(n):
+        c = int(rng.integers(0, len(DEMO_INTENTS)))
+        p = DEMO_PREFIXES[int(rng.integers(0, len(DEMO_PREFIXES)))]
+        out.append((p + DEMO_INTENTS[c], c))
+    return out
 
 
 def _serve_stdio(policy, snap_dir, wal) -> None:
@@ -233,16 +255,24 @@ def _serve_stdio(policy, snap_dir, wal) -> None:
             i += 1
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
+    """The launcher's options (``chip_smoke.py`` parses its phases'
+    settings through this same parser)."""
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="qwen3-1.7b")
+    ap.add_argument("--arch", default="qwen3-1.7b",
+                    help="LLM backend config: a registered arch at its "
+                         "published widths, or '<arch>-smoke' for its "
+                         "2-layer x 64 CPU variant")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="PRNG seed the backend's random weights are "
+                         "drawn from")
     ap.add_argument("--requests", type=int, default=200)
     ap.add_argument("--tau", type=float, default=0.92)
     ap.add_argument("--shards", type=int, default=1,
                     help="serve both tiers row-sharded over this many "
-                         "devices (DESIGN.md §13); on CPU forces a "
-                         "host-device mesh of that size. 1 = the "
-                         "single-device path")
+                         "devices (DESIGN.md §13); with JAX_PLATFORMS=cpu "
+                         "forces a host-device mesh of that size. 1 = "
+                         "the single-device path")
     ap.add_argument("--index", choices=["flat", "ivf"], default="flat",
                     help="static-tier lookup strategy (DESIGN.md §11); "
                          "'ivf' builds the quantized ANN index over the "
@@ -336,35 +366,59 @@ def main() -> None:
                     help="run as a long-lived JSON-lines service on "
                          "stdin/stdout instead of the demo loop (the "
                          "load harness and recovery tests drive this)")
-    args = ap.parse_args()
+    return ap
 
-    # the host-device count must be forced before the first jax import
-    # (all repro imports below touch jax), so do it off the parsed flag:
-    # keep any pre-existing XLA_FLAGS but replace a conflicting
-    # device-count setting with ours — a smaller inherited count would
-    # otherwise make the mesh build fail
-    if args.shards > 1:
-        import re
-        cur = re.sub(r"--xla_force_host_platform_device_count=\S+", "",
-                     os.environ.get("XLA_FLAGS", ""))
-        os.environ["XLA_FLAGS"] = (
-            f"{cur} --xla_force_host_platform_device_count="
-            f"{args.shards}").strip()
 
+def build_engine(args):
+    """The LLM backend ``--arch`` names, its weights drawn from
+    ``--seed``. Batches pad to the front end's 8 rows, so one decode
+    program serves every batch."""
+    from repro.configs import lm_config
+    from repro.serving.engine import LLMEngine
+    return LLMEngine(lm_config(args.arch), seed=args.seed, max_len=96,
+                     min_batch=8)
+
+
+class Service:
+    """A wired serving stack (what :func:`build_service` returns)."""
+
+    def __init__(self, policy, frontend, engine, wal):
+        self.policy = policy
+        self.frontend = frontend
+        self.engine = engine
+        self.wal = wal
+
+    def stop(self) -> None:
+        """Stop the judge pool and the batching front end and close the
+        WAL; the engine (and its compiled programs) stays usable."""
+        self.policy.pool.stop()
+        self.frontend.stop()
+        if self.wal is not None:
+            self.wal.close()
+            self.wal = None
+
+
+def build_service(args, *, engine=None, ivf=None) -> Service:
+    """Wire the serving stack the parsed ``args`` describe: embedder ->
+    tiered cache (KritesPolicy + judge pool, the configured static and
+    dynamic lookups) -> batching front end -> LLM engine, restored from
+    ``--snapshot-dir`` when one is on disk. ``engine`` reuses an
+    already-built backend and ``ivf`` an IVF already packed over this
+    tier, so several services in one process pay for each once."""
     import numpy as np
-    from repro.configs import smoke_config
     from repro.core.judge import OracleJudge, template_rewriter
     from repro.core.policy import KritesPolicy
     from repro.core.tiers import CacheConfig
     from repro.embedding.embedder import Embedder
     from repro.launch.mesh import make_shard_mesh
-    from repro.serving.engine import BatchingFrontend, LLMEngine
+    from repro.serving.engine import BatchingFrontend
 
     from repro.serving import persist
 
     mesh = make_shard_mesh(args.shards) if args.shards > 1 else None
     embed = Embedder(d_out=64)
-    engine = LLMEngine(smoke_config(args.arch), max_len=96)
+    if engine is None:
+        engine = build_engine(args)
     frontend = BatchingFrontend(engine, max_batch=8, max_new_tokens=8)
 
     snap = None
@@ -385,7 +439,7 @@ def main() -> None:
         np.asarray(embed.batch(canon)), [f"[curated] {p}" for p in canon],
         static_rows=args.static_rows,
         index="flat" if warm_ivf else args.index,
-        nprobe=args.nprobe, mesh=mesh, texts=canon)
+        nprobe=args.nprobe, mesh=mesh, texts=canon, ivf=ivf)
     if warm_ivf:
         index = persist.load_static_index(snap, tier.emb,
                                           nprobe=args.nprobe)
@@ -400,13 +454,10 @@ def main() -> None:
 
     fused = None
     if args.fused:
-        if args.index != "flat" or args.dyn_index != "flat" \
-                or args.shards > 1:
-            ap.error("--fused replaces both tier lookups; drop "
-                     "--index ivf / --dyn-index segmented / --shards")
         from repro.index.ivf import build_ivf
         from repro.kernels.fused_serve import FusedServe
-        fused = FusedServe(build_ivf(tier.emb, corpus_normalized=True),
+        fused = FusedServe(ivf if ivf is not None else
+                           build_ivf(tier.emb, corpus_normalized=True),
                            nprobe=args.nprobe)
         print(f"serve path: {fused.describe()}")
 
@@ -496,23 +547,33 @@ def main() -> None:
         if r["replayed"] or not r["clean"]:
             print(f"wal replay: {r['replayed']} promotions "
                   f"(skipped {r['skipped']}, clean={r['clean']})")
+    return Service(policy, frontend, engine, wal)
 
+
+def main() -> None:
+    ap = build_parser()
+    args = ap.parse_args()
+    if args.fused and (args.index != "flat" or args.dyn_index != "flat"
+                       or args.shards > 1):
+        ap.error("--fused replaces both tier lookups; drop "
+                 "--index ivf / --dyn-index segmented / --shards")
+    # the host-device count must be set before the CPU backend starts
+    if args.shards > 1:
+        force_cpu_devices(args.shards)
+    enable_compile_cache()
+    from repro.serving import persist
+
+    svc = build_service(args)
+    policy, wal = svc.policy, svc.wal
     if args.serve_stdio:
         _serve_stdio(policy, args.snapshot_dir, wal)
         if args.snapshot_dir:
             persist.save_snapshot(args.snapshot_dir, policy)
-        policy.pool.stop()
-        frontend.stop()
-        if wal is not None:
-            wal.close()
+        svc.stop()
         return
 
-    rng = np.random.default_rng(0)
-    prefixes = DEMO_PREFIXES
     t0 = time.time()
-    for i in range(args.requests):
-        c = int(rng.integers(0, len(intents)))
-        p = prefixes[int(rng.integers(0, len(prefixes)))] + intents[c]
+    for i, (p, c) in enumerate(demo_requests(args.requests)):
         policy.serve(p, meta={"cls": c})
         if (i + 1) % 50 == 0:
             s = policy.stats()
@@ -530,6 +591,7 @@ def main() -> None:
     print(f"\nfinal ({time.time()-t0:.1f}s):")
     for k, v in s.items():
         print(f"  {k:22s} {v}")
+    print(f"  {'engine_compiles':22s} {svc.engine.stats.compiles}")
     if policy.dyn_index is not None:
         print(f"  {'dyn_index':22s} {policy.describe_dyn_index()}")
     sh = policy.shard_stats()
@@ -543,16 +605,13 @@ def main() -> None:
         path = persist.save_snapshot(args.snapshot_dir, policy)
         print(f"  {'snapshot':22s} {path}")
         if wal is not None:
-            seq = wal.seq
+            seq, wal_path = wal.seq, wal.path
             wal.close()
-            wal = None
+            svc.wal = None
             from repro.core.promo_wal import compact
             kept = compact(wal_path, keep_from_seq=seq)
             print(f"  {'wal_compacted':22s} kept {kept} records")
-    policy.pool.stop()
-    frontend.stop()
-    if wal is not None:
-        wal.close()
+    svc.stop()
 
 
 if __name__ == "__main__":

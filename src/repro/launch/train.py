@@ -7,7 +7,6 @@ On a real TPU pod slice this runs under the production mesh; on CPU pass
 --devices N to force host devices (set before jax init).
 """
 import argparse
-import os
 import sys
 
 
@@ -18,15 +17,16 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--devices", type=int, default=0,
-                    help="force N host devices (CPU testing)")
+                    help="force N host devices (CPU testing; applies "
+                         "with JAX_PLATFORMS=cpu only)")
     ap.add_argument("--ckpt", default="")
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced smoke config")
     args = ap.parse_args()
 
     if args.devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}")
+        from repro.launch.jax_setup import force_cpu_devices
+        force_cpu_devices(args.devices)
 
     import jax
     import jax.numpy as jnp

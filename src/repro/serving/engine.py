@@ -29,13 +29,29 @@ class EngineStats:
     batches: int = 0
     wall_prefill_s: float = 0.0
     wall_decode_s: float = 0.0
+    # distinct (batch, prompt-length) prefill shapes and batch decode
+    # shapes run so far: each is one compile of the whole layer stack
+    compiles: int = 0
+
+
+def _bucket(n: int, lo: int = 1) -> int:
+    """Smallest power of two >= max(n, lo)."""
+    return 1 << (max(n, lo) - 1).bit_length()
 
 
 class LLMEngine:
-    """Synchronous batched generate; thread-safe via internal lock."""
+    """Synchronous batched generate; thread-safe via internal lock.
+
+    Batches are padded to a power-of-two row count (at least
+    ``min_batch``) and prompts to a power-of-two length (at least 16
+    tokens), so a stream of ragged requests compiles a bounded set of
+    prefill/decode programs — ``stats.compiles`` counts them. A front
+    end that never sends more than ``min_batch`` rows thus runs one
+    decode program."""
 
     def __init__(self, cfg: LMConfig, params=None, seed: int = 0,
-                 max_len: int = 256, temperature: float = 0.0):
+                 max_len: int = 256, temperature: float = 0.0,
+                 min_batch: int = 1):
         self.cfg = cfg
         self.tok = ByteTokenizer()
         assert cfg.vocab_size >= self.tok.vocab_size
@@ -43,8 +59,10 @@ class LLMEngine:
             cfg, jax.random.PRNGKey(seed))
         self.max_len = max_len
         self.temperature = temperature
+        self.min_batch = min_batch
         self.stats = EngineStats()
         self._lock = threading.Lock()
+        self._shapes: set = set()
 
         self._prefill = jax.jit(
             lambda p, t: tr.prefill(cfg, p, t, max_len=max_len))
@@ -58,17 +76,23 @@ class LLMEngine:
 
     def _generate(self, prompts: List[str], max_new: int) -> List[str]:
         B = len(prompts)
-        in_len = max(8, max(len(p.encode()) + 2 for p in prompts))
+        Bp = _bucket(B, self.min_batch)
+        in_len = _bucket(max(len(p.encode()) + 2 for p in prompts), 16)
         in_len = min(in_len, self.max_len - max_new)
         toks = np.stack([self.tok.encode(p, max_len=in_len)
                          for p in prompts])
+        # pad rows repeat the first prompt; their tokens are dropped
+        toks = np.concatenate([toks, np.repeat(toks[:1], Bp - B, 0)])
+        self._shapes |= {("prefill", Bp, in_len), ("decode", Bp)}
+        self.stats.compiles = len(self._shapes)
         t0 = time.monotonic()
         logits, cache = self._prefill(self.params, jnp.asarray(toks))
         self.stats.prefills += B
         self.stats.wall_prefill_s += time.monotonic() - t0
 
         out = [[] for _ in range(B)]
-        done = np.zeros(B, bool)
+        done = np.zeros(Bp, bool)
+        done[B:] = True
         tok = self._sample(logits)
         t0 = time.monotonic()
         for _ in range(max_new):

@@ -102,12 +102,15 @@ def test_small_mesh_train_step_lowers_with_shardings():
         from repro.configs import smoke_config
         from repro.distributed import sharding as shd
         from repro.distributed.act_sharding import use_dp_axes
+        from repro.launch.mesh import make_mesh
         from repro.models import transformer as tr
         from repro.training import optimizer as opt
         cfg = dataclasses.replace(
             smoke_config("qwen3-1.7b"), d_model=64, n_heads=4,
             n_kv_heads=4, head_dim=16, d_ff=128, vocab_size=512)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        # the production sharding rules are GSPMD hints, which need the
+        # Auto axes launch.mesh.make_mesh gives
+        mesh = make_mesh((2, 4), ("data", "model"))
         ns = lambda s: NamedSharding(mesh, s)
         p_specs = shd.lm_param_specs(cfg)
         p_shard = jax.tree.map(ns, p_specs,

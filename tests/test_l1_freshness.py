@@ -177,7 +177,7 @@ def test_ttl_liveness_downward_closed_in_time(ttl, wr, d1, d2):
     assert (tier.get("k", now=n1) is not None) == _live(exp, n1)
 
 
-@settings(max_examples=40)
+@settings(max_examples=40, deadline=None)
 @given(st.integers(0, 2**31 - 1), st.integers(1, 64), st.integers(1, 200))
 def test_evict_expired_per_entry_matches_legacy_ttl(seed, ttl, now):
     """Satellite pin: the per-entry ``expires_at`` path of

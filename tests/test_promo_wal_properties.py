@@ -130,7 +130,7 @@ def test_vector_roundtrip_bit_exact():
 
 
 @given(OPS)
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)
 def test_append_scan_roundtrip(ops):
     with _wal_path() as path:
         with PromotionWAL(path, fsync_every=4) as wal:
@@ -149,7 +149,7 @@ def test_append_scan_roundtrip(ops):
 # ---------------------------------------------------------------------------
 
 @given(OPS, st.floats(0.0, 1.0))
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)
 def test_any_truncation_scans_to_valid_prefix(ops, cut_frac):
     with _wal_path() as path:
         with PromotionWAL(path, fsync_every=1) as wal:
@@ -173,7 +173,7 @@ def test_any_truncation_scans_to_valid_prefix(ops, cut_frac):
 
 
 @given(OPS, st.floats(0.0, 1.0))
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)
 def test_single_byte_corruption_never_raises(ops, pos_frac):
     with _wal_path() as path:
         with PromotionWAL(path, fsync_every=1) as wal:
@@ -196,7 +196,7 @@ def test_single_byte_corruption_never_raises(ops, pos_frac):
 # ---------------------------------------------------------------------------
 
 @given(OPS, st.integers(1, 3))
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 def test_replay_idempotent_and_matches_live(ops, n_replays):
     with _wal_path() as path:
         live = _policy(wal=PromotionWAL(path, fsync_every=1))
@@ -214,7 +214,7 @@ def test_replay_idempotent_and_matches_live(ops, n_replays):
 
 
 @given(OPS)
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 def test_lww_interleaving_matches_numpy_oracle(ops):
     """Same op stream through three implementations — live policy,
     journal replay, and the independent ``ref_policy._Dyn`` upsert loop
@@ -298,7 +298,7 @@ def test_equal_timestamp_replay_beats_miss_insert(tmp_path):
 # ---------------------------------------------------------------------------
 
 @given(OPS, st.floats(0.0, 1.0))
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 def test_compact_preserves_cursor_and_seq(ops, keep_frac):
     with _wal_path() as path:
         live = _policy(wal=PromotionWAL(path, fsync_every=1))
