@@ -54,27 +54,6 @@ class Failed(SystemExit):
         super().__init__(f"chip_smoke: FAILED: {msg}")
 
 
-class CompileCounter:
-    """Backend compiles and their seconds, from JAX's monitoring
-    events (a persistent-cache hit is not a compile)."""
-
-    EVENT = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self):
-        import jax
-        self.n, self.secs = 0, 0.0
-
-        def on_duration(event, secs, **_):
-            if event == self.EVENT:
-                self.n += 1
-                self.secs += secs
-
-        jax.monitoring.register_event_duration_secs_listener(on_duration)
-
-    def mark(self):
-        return self.n, self.secs
-
-
 def ref_top1(V, E, valid=None, chunk: int = 131072):
     """float32 numpy top-1 of V (B, d) over the rows of E (N, d):
     (score, lowest index of the max, gap to the runner-up). Rows
@@ -145,15 +124,17 @@ def serve_checked(svc, reqs, tau):
     return results, ok, n, err
 
 
-def run_phase(name, svc_argv, reqs, counter, *, engine=None, ivf=None,
+def run_phase(name, svc_argv, reqs, *, engine=None, ivf=None,
               device=None):
     """Build one service, serve ``reqs`` twice, check it and stop it.
-    Returns its policy and both passes' results."""
+    Returns its policy and both passes' results. Its compiles are
+    ``repro.tracing``'s count (a persistent-cache load is not one)."""
     import collections
 
+    from repro import tracing
     from repro.launch.serve import build_parser, build_service
     args = build_parser().parse_args(svc_argv)
-    n0, s0 = counter.mark()
+    n0, s0 = tracing.compiles()
     t0 = time.monotonic()
     svc = build_service(args, engine=engine, ivf=ivf)
     build_s = time.monotonic() - t0
@@ -161,7 +142,7 @@ def run_phase(name, svc_argv, reqs, counter, *, engine=None, ivf=None,
     r1, ok1, n1, e1 = serve_checked(svc, reqs, args.tau)
     r2, ok2, n2, e2 = serve_checked(svc, reqs, args.tau)
     serve_s = time.monotonic() - t0
-    n1c, s1c = counter.mark()
+    n1c, s1c = tracing.compiles()
     st = svc.policy.stats()
     promoted_hits = sum(r.served_by == "dynamic" and r.static_origin
                         for r in r2)
@@ -207,24 +188,24 @@ def base_argv(opts):
             "--nprobe", str(opts.nprobe)]
 
 
-def run_one_chip(opts, counter, device=None):
+def run_one_chip(opts, device=None):
     """flat, ivf, segmented and fused phases; the ivf and fused phases
     share one IVF build."""
     from repro.launch.serve import build_engine, build_parser, demo_requests
     base = base_argv(opts)
     engine = build_engine(build_parser().parse_args(base))
     reqs = demo_requests(opts.requests)
-    run_phase("flat", base, reqs, counter, engine=engine, device=device)
-    pol, _ = run_phase("ivf", base + ["--index", "ivf"], reqs, counter,
+    run_phase("flat", base, reqs, engine=engine, device=device)
+    pol, _ = run_phase("ivf", base + ["--index", "ivf"], reqs,
                        engine=engine, device=device)
     run_phase("segmented", base + ["--dyn-index", "segmented",
                                    "--seg-rows", str(SEG_ROWS)],
-              reqs, counter, engine=engine, device=device)
-    run_phase("fused", base + ["--fused"], reqs, counter, engine=engine,
+              reqs, engine=engine, device=device)
+    run_phase("fused", base + ["--fused"], reqs, engine=engine,
               ivf=pol.index.ivf, device=device)
 
 
-def run_four_chips(opts, counter, device=None):
+def run_four_chips(opts, device=None):
     """``--shards 4`` flat and IVF against one-chip flat and IVF on the
     same requests: decisions must be identical."""
     from repro.launch.serve import build_engine, build_parser, demo_requests
@@ -236,7 +217,7 @@ def run_four_chips(opts, counter, device=None):
         for shards in (1, 4):
             argv = base + ["--index", index, "--shards", str(shards)]
             _, res = run_phase(f"{index}-shards{shards}", argv, reqs,
-                               counter, engine=engine, device=device)
+                               engine=engine, device=device)
             runs[shards] = [(r.served_by, bool(r.static_origin))
                             for r in res]
         diff = sum(a != b for a, b in zip(runs[1], runs[4]))
@@ -275,7 +256,7 @@ def main(argv=None) -> None:
     if len(devs) < opts.chips:
         raise Failed(f"--chips {opts.chips} but JAX sees {len(devs)}")
     run = run_four_chips if opts.chips == 4 else run_one_chip
-    run(opts, CompileCounter(), device=devs[0])
+    run(opts, device=devs[0])
     print(json.dumps({"ok": True, "device": {
         "platform": devs[0].platform, "kind": devs[0].device_kind,
         "count": len(devs)}}))

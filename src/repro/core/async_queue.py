@@ -16,6 +16,11 @@ outcome — the action, not the verdict, is what retries.
 
 Everything is off the serving path: ``submit`` never blocks and serving
 never waits on this pool. Queue depth only delays promotions (§3.1).
+
+Tracing (``repro.tracing``): a task's wait from enqueue to dequeue is
+the counter ``judge.queue_wait_s``, the judge call the span
+``judge.call``, and every failure (retried or given up) counts under
+``judge.failed.<exception type>``.
 """
 from __future__ import annotations
 
@@ -27,6 +32,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, Optional
 
+from repro import tracing
 from repro.core.judge import APPROVE, REJECT, REWRITE, as_verdict
 
 
@@ -218,8 +224,11 @@ class VerifyAndPromotePool:
                 task = self.q.get(timeout=0.1)
             except queue.Empty:
                 continue
+            tracing.add("judge.queue_wait_s",
+                        time.monotonic() - task.enqueued_at)
             try:
-                verdict = as_verdict(self.judge_fn(task.payload))
+                with tracing.span("judge.call"):
+                    verdict = as_verdict(self.judge_fn(task.payload))
                 action = self.actions.get(verdict.outcome)
                 with self._lock:
                     self.stats.judged += 1
@@ -251,7 +260,8 @@ class VerifyAndPromotePool:
                             self.stats.rewrite_failed += 1
                         if task.payload.get("rewrite_rate_limited"):
                             self.stats.rewrite_rate_limited += 1
-            except Exception:  # noqa: BLE001 — transient failure: retry
+            except Exception as e:  # noqa: BLE001 — transient: retry
+                tracing.add(f"judge.failed.{type(e).__name__}", 1)
                 task.attempts += 1
                 if task.attempts < self._max_attempts:
                     # deadline-based requeue: park the task until its

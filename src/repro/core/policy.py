@@ -56,6 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import adaptive as A
 from repro.core import tiers as T
 from repro.core.async_queue import VerifyAndPromotePool
@@ -677,9 +678,16 @@ class BaselinePolicy:
         under L1 *capacity pressure within a single batch* the LRU
         eviction order can differ from scalar serving (the semantic
         decisions never do).
+
+        Each call is one serve batch to ``repro.tracing``: the span
+        ``policy.serve_batch`` with its steps as child spans.
         """
         if not prompts:
             return []
+        with tracing.span("policy.serve_batch", rows=len(prompts)):
+            return self._serve_batch(prompts, metas)
+
+    def _serve_batch(self, prompts, metas) -> List[ServeResult]:
         t0 = time.monotonic()
         B = len(prompts)
         metas = list(metas) if metas is not None else [None] * B
@@ -690,29 +698,30 @@ class BaselinePolicy:
         keys: List[Optional[str]] = [None] * B
         vol = [False] * B
         exp_of = [0] * B     # L1 expiry stamp for producer rows
-        if fresh is not None or self.l1 is not None:
-            pend: dict = {}  # canon key -> (producer row, expires_at)
-            for i in range(B):
-                ti = self.t + i + 1
-                volatile = fresh is not None \
-                    and fresh.is_volatile(prompts[i])
-                vol[i] = volatile
-                if volatile and fresh.volatile_bypass:
-                    front[i] = ("bypass",)
-                    continue
-                if self.l1 is None:
-                    continue
-                k = canonicalize(prompts[i])
-                keys[i] = k
-                e = self.l1.get(k, ti)
-                if e is not None:
-                    front[i] = ("hit", e)
-                elif k in pend and (pend[k][1] == 0
-                                    or ti <= pend[k][1]):
-                    front[i] = ("dup", pend[k][0])
-                else:
-                    exp_of[i] = self._entry_expiry(prompts[i], ti)
-                    pend[k] = (i, exp_of[i])
+        with tracing.span("policy.front", rows=B):
+            if fresh is not None or self.l1 is not None:
+                pend: dict = {}  # canon key -> (producer row, expires_at)
+                for i in range(B):
+                    ti = self.t + i + 1
+                    volatile = fresh is not None \
+                        and fresh.is_volatile(prompts[i])
+                    vol[i] = volatile
+                    if volatile and fresh.volatile_bypass:
+                        front[i] = ("bypass",)
+                        continue
+                    if self.l1 is None:
+                        continue
+                    k = canonicalize(prompts[i])
+                    keys[i] = k
+                    e = self.l1.get(k, ti)
+                    if e is not None:
+                        front[i] = ("hit", e)
+                    elif k in pend and (pend[k][1] == 0
+                                        or ti <= pend[k][1]):
+                        front[i] = ("dup", pend[k][0])
+                    else:
+                        exp_of[i] = self._entry_expiry(prompts[i], ti)
+                        pend[k] = (i, exp_of[i])
         sem = [i for i in range(B) if i not in front]
         pos_of = {i: p for p, i in enumerate(sem)}
 
@@ -724,30 +733,35 @@ class BaselinePolicy:
         if sem:
             Bs = len(sem)
             Bp = 1 << (Bs - 1).bit_length()
-            V = self._embed_batch([prompts[i] for i in sem])   # (Bs, d)
-            if Bp != Bs:
-                V = jnp.pad(V, ((0, Bp - Bs), (0, 0)))
-            # degenerate-embedding guard (same contract as the scalar
-            # path): zero out unusable rows so one NaN can't leak
-            # through the fused lookups, and serve them backend-only
-            # further down — never cached, never grey-triggered
-            ok = _usable_rows(np.asarray(V)[:Bs])
-            if not ok.all():
-                V = jnp.where(
-                    jnp.asarray(np.pad(ok, (0, Bp - Bs)))[:, None],
-                    V, 0.0)
-            V_np = np.asarray(V)[:Bs]
+            with tracing.span("policy.embed", rows=Bs):
+                V = self._embed_batch([prompts[i] for i in sem])  # (Bs, d)
+                if Bp != Bs:
+                    V = jnp.pad(V, ((0, Bp - Bs), (0, 0)))
+                # degenerate-embedding guard (same contract as the
+                # scalar path): zero out unusable rows so one NaN can't
+                # leak through the fused lookups, and serve them
+                # backend-only further down — never cached, never
+                # grey-triggered
+                ok = _usable_rows(np.asarray(V)[:Bs])
+                if not ok.all():
+                    V = jnp.where(
+                        jnp.asarray(np.pad(ok, (0, Bp - Bs)))[:, None],
+                        V, 0.0)
+                V_np = np.asarray(V)[:Bs]
             if self.fused is None:
-                s_sb, h_idxb = jax.device_get(
-                    self._static_topk_batch(V))               # fused top-1
+                with tracing.span("policy.static_lookup", rows=Bs):
+                    s_sb, h_idxb = jax.device_get(
+                        self._static_topk_batch(V))           # fused top-1
                 s_sb, h_idxb = s_sb[:Bs], h_idxb[:Bs]
 
         results: List[Optional[ServeResult]] = [None] * B
         content_of = [0] * B    # per-row content clock (drift accounting)
         grey_rows = []          # static-miss rows, for the Krites hook
         l1_dup_fill = []        # (row, producer row) — answer arrives late
-        ev0 = len(self.events)  # rollback point: a failed batch serves
-        with self.dyn_lock:     # nobody, so it must record no events
+        # rollback point: a failed batch serves nobody, so it must
+        # record no events
+        ev0 = len(self.events)
+        with tracing.locked(self.dyn_lock, "policy.lock_wait"):
             # one masked lookup against the dynamic-tier snapshot; the
             # tier object is immutable, so `snap` stays the batch-start
             # state while mutations accumulate on the host
@@ -756,12 +770,15 @@ class BaselinePolicy:
                 if self.fused is not None:
                     # fused fast path (DESIGN.md §15): static probe +
                     # masked dynamic top-1 in ONE dispatch over the batch
-                    s_sb, h_idxb, s_db, j_db = jax.device_get(
-                        T.serve_lookup_batch(self.static, snap, V,
-                                             self.fused))
+                    with tracing.span("policy.lookup", rows=len(sem)):
+                        s_sb, h_idxb, s_db, j_db = jax.device_get(
+                            T.serve_lookup_batch(self.static, snap, V,
+                                                 self.fused))
                     s_sb, h_idxb = s_sb[:len(sem)], h_idxb[:len(sem)]
                 else:
-                    s_db, j_db = jax.device_get(self._dyn_topk(snap, V))
+                    with tracing.span("policy.dyn_lookup", rows=len(sem)):
+                        s_db, j_db = jax.device_get(
+                            self._dyn_topk(snap, V))
                 s_db, j_db = s_db[:len(sem)], j_db[:len(sem)]
 
             written: dict = {}   # slot -> (row, pos) of its last writer
@@ -923,8 +940,10 @@ class BaselinePolicy:
             if backend_rows:
                 try:
                     # one batched backend call amortizes prefill
-                    answers = self._backend_batch(
-                        [prompts[i] for i in backend_rows])
+                    with tracing.span("policy.backend",
+                                      rows=len(backend_rows)):
+                        answers = self._backend_batch(
+                            [prompts[i] for i in backend_rows])
                 except Exception:
                     for slot, st in saved.items():
                         (self._valid_np[slot], self._last_used_np[slot],
@@ -937,7 +956,9 @@ class BaselinePolicy:
                     self._apply_batch_writes(V, {}, touched, Bp,
                                              dead=dead)
                     raise
-            self._apply_batch_writes(V, w_meta, touched, Bp, dead=dead)
+            with tracing.span("policy.writes", rows=len(w_meta)):
+                self._apply_batch_writes(V, w_meta, touched, Bp,
+                                         dead=dead)
             if backend_rows:
                 for slot, i, ans in zip(backend_slots, backend_rows,
                                         answers):
@@ -966,8 +987,10 @@ class BaselinePolicy:
         lat = time.monotonic() - t0
         for r in results:
             r.latency_s = lat
-        self._after_static_miss_batch(grey_rows)
-        self._maybe_adapt()
+        with tracing.span("policy.grey_submit", rows=len(grey_rows)):
+            self._after_static_miss_batch(grey_rows)
+        with tracing.span("policy.adapt"):
+            self._maybe_adapt()
         return results  # type: ignore[return-value]
 
     def _apply_batch_writes(self, V: jax.Array, w_meta: dict,
@@ -1257,6 +1280,7 @@ class KritesPolicy(BaselinePolicy):
             "v": va,
             "h_idx": h_idx,
             "enq_t": enq_t,
+            "submitted_s": time.monotonic(),   # for promote.lag_s
             "adapt_seq": res.meta.get("adapt_seq", 0),
             "judge_args": {
                 "q_cls": (meta or {}).get("cls", -1),
@@ -1305,7 +1329,16 @@ class KritesPolicy(BaselinePolicy):
         *live* clock — a promotion applied after a slow judge is fresh
         state; stamping its LRU clock with the stale ``enq_t`` would
         make it the coldest entry in the tier and the eviction victim
-        of the very next insert under churn."""
+        of the very next insert under churn.
+
+        Spans: ``promote`` with children ``promote.lock_wait``,
+        ``promote.lookup``, ``promote.wal`` and ``promote.write``; a
+        landed upsert adds its lag from the pool submit to the counter
+        ``promote.lag_s``."""
+        with tracing.span("promote"):
+            self._apply_promotion(payload, journal)
+
+    def _apply_promotion(self, payload: dict, journal: bool) -> None:
         h_idx = payload["h_idx"]
         v = jnp.asarray(payload["v"])
         enq_t = payload["enq_t"]
@@ -1330,7 +1363,7 @@ class KritesPolicy(BaselinePolicy):
             answer = self._serve_static(h_idx)
             cls = int(self._static_cls_np[h_idx])
             ref = int(self._static_ref_np[h_idx])
-        with self.dyn_lock:
+        with tracing.locked(self.dyn_lock, "promote.lock_wait"):
             apply_t = self.t      # live LRU clock, read under the lock
             self._sweep_expired_locked(apply_t)
             if exp and exp < apply_t:
@@ -1338,13 +1371,14 @@ class KritesPolicy(BaselinePolicy):
             # the async promotion path rides the same index: dedup
             # lookup through the segmented tail/segments (§12) or the
             # row-sharded masked scan (§13), fresh write into the tier
-            if self.mesh is not None:
-                sd, jd = self._sh_dyn_fn(self.dyn, v[None])
-                s_d, j = float(sd[0]), int(jd[0])
-            else:
-                s_d, j = T.dynamic_lookup(self.dyn, v,
-                                          index=self.dyn_index)
-                s_d, j = float(s_d), int(j)
+            with tracing.span("promote.lookup"):
+                if self.mesh is not None:
+                    sd, jd = self._sh_dyn_fn(self.dyn, v[None])
+                    s_d, j = float(sd[0]), int(jd[0])
+                else:
+                    s_d, j = T.dynamic_lookup(self.dyn, v,
+                                              index=self.dyn_index)
+                    s_d, j = float(s_d), int(j)
             dup = s_d >= self.cfg.dup_threshold
             if dup and self._written_at_np[j] > enq_t:
                 return       # LWW: a newer write owns this key
@@ -1355,25 +1389,30 @@ class KritesPolicy(BaselinePolicy):
             # live tier rightly refused, forever
             if journal and self.wal is not None:
                 from repro.core.promo_wal import encode_record
-                self.wal.append(encode_record(
-                    payload["v"], h_idx, enq_t, ttl=ttl,
-                    q_text=ja.get("q_text", ""),
-                    h_text=ja.get("h_text", ""),
-                    outcome=REWRITE if rewrite else APPROVE,
-                    rewritten=str(answer) if rewrite else "",
-                    q_cls=int(ja.get("q_cls", -1))))
-            slot = j if dup else self._host_lru_slot()
-            self.dyn = self._write_fn(
-                self.dyn, slot, v,
-                jnp.int32(cls), jnp.int32(ref),
-                jnp.asarray(True), enq_t, last_used=apply_t,
-                expires=exp)
-            self._mirror_write(slot, apply_t, static_origin=True,
-                               written_at=enq_t, expires=exp,
-                               rewritten=rewrite)
-            if self.dyn_index is not None:
-                self.dyn_index.record_write(slot, payload["v"])
-            self.dyn_answers[slot] = answer
+                with tracing.span("promote.wal"):
+                    self.wal.append(encode_record(
+                        payload["v"], h_idx, enq_t, ttl=ttl,
+                        q_text=ja.get("q_text", ""),
+                        h_text=ja.get("h_text", ""),
+                        outcome=REWRITE if rewrite else APPROVE,
+                        rewritten=str(answer) if rewrite else "",
+                        q_cls=int(ja.get("q_cls", -1))))
+            with tracing.span("promote.write"):
+                slot = j if dup else self._host_lru_slot()
+                self.dyn = self._write_fn(
+                    self.dyn, slot, v,
+                    jnp.int32(cls), jnp.int32(ref),
+                    jnp.asarray(True), enq_t, last_used=apply_t,
+                    expires=exp)
+                self._mirror_write(slot, apply_t, static_origin=True,
+                                   written_at=enq_t, expires=exp,
+                                   rewritten=rewrite)
+                if self.dyn_index is not None:
+                    self.dyn_index.record_write(slot, payload["v"])
+                self.dyn_answers[slot] = answer
+            if "submitted_s" in payload:      # not on a WAL replay
+                tracing.add("promote.lag_s",
+                            time.monotonic() - payload["submitted_s"])
 
     def stats(self) -> dict:
         out = super().stats()

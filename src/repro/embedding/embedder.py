@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
+
 
 def _ngrams(text: str, lo: int = 2, hi: int = 4):
     t = re.sub(r"\s+", " ", text.lower().strip())
@@ -68,6 +70,12 @@ class Embedder:
         return np.asarray(self._fwd(feats[None])[0])
 
     def batch(self, texts) -> np.ndarray:
-        feats = jnp.asarray(
-            np.stack([hash_features(t, self.n_features) for t in texts]))
-        return np.asarray(self._fwd(feats))
+        """Embed ``texts``: host n-gram hashing (span ``embed.featurize``),
+        then the MLP on the device through the copy back to the host
+        (span ``embed.encode``)."""
+        texts = list(texts)
+        with tracing.span("embed.featurize", rows=len(texts)):
+            feats = np.stack([hash_features(t, self.n_features)
+                              for t in texts])
+        with tracing.span("embed.encode", rows=len(texts)):
+            return np.asarray(self._fwd(jnp.asarray(feats)))

@@ -148,12 +148,16 @@ def _serve_stdio(policy, snap_dir, wal) -> None:
     line, one JSON reply per line on stdout. Messages are processed in
     arrival order; consecutive ``serve`` ops already queued are
     coalesced into a single ``serve_batch`` call (the stdio twin of the
-    router's micro-batcher). Control ops: ``stats``, ``snapshot``,
-    ``drain``, ``shutdown``."""
+    router's micro-batcher). Control ops: ``stats`` (with the span and
+    counter aggregates, ``repro.tracing.snapshot()``, under ``trace``),
+    ``snapshot``, ``drain``, ``shutdown``. Each request's wait from its
+    read to the start of its batch is the counter
+    ``loop.queue_wait_s``."""
     import json
     import queue as _q
     import threading
 
+    from repro import tracing
     from repro.distributed import checkpoint as ckpt
     from repro.serving import persist
 
@@ -163,7 +167,7 @@ def _serve_stdio(policy, snap_dir, wal) -> None:
         for line in sys.stdin:
             line = line.strip()
             if line:
-                inq.put(line)
+                inq.put((time.perf_counter(), line))
         inq.put(None)
 
     threading.Thread(target=_reader, daemon=True,
@@ -173,18 +177,24 @@ def _serve_stdio(policy, snap_dir, wal) -> None:
         sys.stdout.write(json.dumps(obj) + "\n")
         sys.stdout.flush()
 
-    def _serve_run(msgs: list) -> None:
+    def _serve_run(msgs: list, read_at: list) -> None:
+        t = time.perf_counter()
+        for r in read_at:
+            tracing.add("loop.queue_wait_s", t - r)
+        tracing.add("loop.batch_rows", len(msgs))
         results = policy.serve_batch(
             [m.get("prompt", "") for m in msgs],
             [{"cls": m["cls"]} if "cls" in m else None for m in msgs])
-        for m, r in zip(msgs, results):
-            emit({"ok": True, "id": m.get("id"),
-                  "served_by": r.served_by,
-                  "static_origin": bool(r.static_origin),
-                  "similarity": float(r.similarity),
-                  "stale": bool(r.meta.get("stale", False)),
-                  "bypass": r.meta.get("bypass"),
-                  "answer": None if r.answer is None else str(r.answer)})
+        with tracing.span("loop.reply", rows=len(msgs)):
+            for m, r in zip(msgs, results):
+                emit({"ok": True, "id": m.get("id"),
+                      "served_by": r.served_by,
+                      "static_origin": bool(r.static_origin),
+                      "similarity": float(r.similarity),
+                      "stale": bool(r.meta.get("stale", False)),
+                      "bypass": r.meta.get("bypass"),
+                      "answer": None if r.answer is None
+                      else str(r.answer)})
 
     emit({"ok": True, "ready": True, "pid": os.getpid(),
           "t": policy.t, "wal_seq":
@@ -205,10 +215,11 @@ def _serve_stdio(policy, snap_dir, wal) -> None:
                 break
             batch.append(nxt)
 
-        msgs = []
-        for ln in batch:
+        msgs, read_at = [], []
+        for t_read, ln in batch:
             try:
                 msgs.append(json.loads(ln))
+                read_at.append(t_read)
             except ValueError:
                 emit({"ok": False, "error": f"bad json: {ln[:80]!r}"})
         i = 0
@@ -220,7 +231,7 @@ def _serve_stdio(policy, snap_dir, wal) -> None:
                 while j < len(msgs) and \
                         msgs[j].get("op", "serve") == "serve":
                     j += 1
-                _serve_run(msgs[i:j])
+                _serve_run(msgs[i:j], read_at[i:j])
                 i = j
                 continue
             if op == "stats":
@@ -229,7 +240,8 @@ def _serve_stdio(policy, snap_dir, wal) -> None:
                 depth = policy.pool.depth()
                 s["judge_queued"] = depth["queued"]
                 s["judge_inflight"] = depth["inflight"]
-                emit({"ok": True, "id": msg.get("id"), "stats": s})
+                emit({"ok": True, "id": msg.get("id"), "stats": s,
+                      "trace": tracing.snapshot()})
             elif op == "snapshot":
                 if snap_dir is None:
                     emit({"ok": False, "id": msg.get("id"),

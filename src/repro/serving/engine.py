@@ -8,7 +8,6 @@ at full scale on TPU; smoke configs on CPU for the examples/tests).
 from __future__ import annotations
 
 import threading
-import time
 from dataclasses import dataclass
 from typing import List
 
@@ -16,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.configs.base import LMConfig
 from repro.data.tokenizer import ByteTokenizer, EOS, PAD
 from repro.models import transformer as tr
@@ -27,8 +27,6 @@ class EngineStats:
     decode_steps: int = 0
     generated_tokens: int = 0
     batches: int = 0
-    wall_prefill_s: float = 0.0
-    wall_decode_s: float = 0.0
     # distinct (batch, prompt-length) prefill shapes and batch decode
     # shapes run so far: each is one compile of the whole layer stack
     compiles: int = 0
@@ -47,7 +45,13 @@ class LLMEngine:
     tokens), so a stream of ragged requests compiles a bounded set of
     prefill/decode programs — ``stats.compiles`` counts them. A front
     end that never sends more than ``min_batch`` rows thus runs one
-    decode program."""
+    decode program.
+
+    Spans (``repro.tracing``): ``engine.batch`` per call, inside it
+    ``engine.prefill`` (tokenize, prefill, first sample) and one
+    ``engine.decode`` per step, each with ``engine.sample`` (the argmax
+    and its copy to the host) as a child. A decode step whose tokens
+    no row takes counts under ``engine.decode_steps_unserved``."""
 
     def __init__(self, cfg: LMConfig, params=None, seed: int = 0,
                  max_len: int = 256, temperature: float = 0.0,
@@ -64,37 +68,40 @@ class LLMEngine:
         self._lock = threading.Lock()
         self._shapes: set = set()
 
-        self._prefill = jax.jit(
-            lambda p, t: tr.prefill(cfg, p, t, max_len=max_len))
-        self._decode = jax.jit(
-            lambda p, c, t: tr.decode_step(cfg, p, c, t))
+        def prefill(params, tokens):
+            return tr.prefill(cfg, params, tokens, max_len=max_len)
+
+        def decode(params, cache, tokens):
+            return tr.decode_step(cfg, params, cache, tokens)
+
+        # named, so the profiler's programs read jit_prefill / jit_decode
+        self._prefill = jax.jit(prefill)
+        self._decode = jax.jit(decode)
 
     def generate_batch(self, prompts: List[str],
                        max_new_tokens: int = 32) -> List[str]:
-        with self._lock:
+        with self._lock, tracing.span("engine.batch", rows=len(prompts)):
             return self._generate(prompts, max_new_tokens)
 
     def _generate(self, prompts: List[str], max_new: int) -> List[str]:
         B = len(prompts)
         Bp = _bucket(B, self.min_batch)
-        in_len = _bucket(max(len(p.encode()) + 2 for p in prompts), 16)
-        in_len = min(in_len, self.max_len - max_new)
-        toks = np.stack([self.tok.encode(p, max_len=in_len)
-                         for p in prompts])
-        # pad rows repeat the first prompt; their tokens are dropped
-        toks = np.concatenate([toks, np.repeat(toks[:1], Bp - B, 0)])
-        self._shapes |= {("prefill", Bp, in_len), ("decode", Bp)}
-        self.stats.compiles = len(self._shapes)
-        t0 = time.monotonic()
-        logits, cache = self._prefill(self.params, jnp.asarray(toks))
-        self.stats.prefills += B
-        self.stats.wall_prefill_s += time.monotonic() - t0
+        with tracing.span("engine.prefill", rows=B):
+            in_len = _bucket(max(len(p.encode()) + 2 for p in prompts), 16)
+            in_len = min(in_len, self.max_len - max_new)
+            toks = np.stack([self.tok.encode(p, max_len=in_len)
+                             for p in prompts])
+            # pad rows repeat the first prompt; their tokens are dropped
+            toks = np.concatenate([toks, np.repeat(toks[:1], Bp - B, 0)])
+            self._shapes |= {("prefill", Bp, in_len), ("decode", Bp)}
+            self.stats.compiles = len(self._shapes)
+            logits, cache = self._prefill(self.params, jnp.asarray(toks))
+            self.stats.prefills += B
+            tok = self._sample(logits)
 
         out = [[] for _ in range(B)]
         done = np.zeros(Bp, bool)
         done[B:] = True
-        tok = self._sample(logits)
-        t0 = time.monotonic()
         for _ in range(max_new):
             for b in range(B):
                 if not done[b]:
@@ -102,21 +109,25 @@ class LLMEngine:
                     done[b] |= int(tok[b]) == EOS
             if done.all():
                 break
-            logits, cache = self._decode(self.params, cache,
-                                         jnp.asarray(tok))
-            self.stats.decode_steps += 1
-            tok = self._sample(logits)
-        self.stats.wall_decode_s += time.monotonic() - t0
+            with tracing.span("engine.decode", rows=B):
+                logits, cache = self._decode(self.params, cache,
+                                             jnp.asarray(tok))
+                self.stats.decode_steps += 1
+                tok = self._sample(logits)
+        else:
+            if max_new:          # the last step's tokens are never taken
+                tracing.add("engine.decode_steps_unserved", 1)
         self.stats.generated_tokens += sum(len(o) for o in out)
         self.stats.batches += 1
         return [self.tok.decode(o) for o in out]
 
     def _sample(self, logits) -> np.ndarray:
-        if self.temperature <= 0:
-            return np.asarray(jnp.argmax(logits, -1), np.int32)
-        g = np.random.gumbel(size=logits.shape)
-        return np.asarray(
-            jnp.argmax(logits / self.temperature + g, -1), np.int32)
+        with tracing.span("engine.sample", rows=logits.shape[0]):
+            if self.temperature <= 0:
+                return np.asarray(jnp.argmax(logits, -1), np.int32)
+            g = np.random.gumbel(size=logits.shape)
+            return np.asarray(
+                jnp.argmax(logits / self.temperature + g, -1), np.int32)
 
     def generate(self, prompt: str, max_new_tokens: int = 32) -> str:
         return self.generate_batch([prompt], max_new_tokens)[0]

@@ -25,6 +25,8 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, List, Optional, Sequence
 
+from repro import tracing
+
 
 @dataclass
 class _PendingRequest:
@@ -44,12 +46,16 @@ class _MicroBatcher:
     fills each ``result``; if it raises, every request in the batch gets
     the exception on ``error`` instead. Completion events are always set,
     so callers never hang on a failed batch.
+
+    Each request's wait from ``submit`` to the start of its batch's
+    ``serve_fn`` is the counter ``<name>.wait_s``.
     """
 
     def __init__(self, serve_fn: Callable[[List[_PendingRequest]], None],
                  max_batch: int, max_wait_s: float,
                  name: str = "micro-batcher"):
         self.serve_fn = serve_fn
+        self.name = name
         self.max_batch = max_batch
         self.max_wait = max_wait_s
         self.q: "queue.Queue[_PendingRequest]" = queue.Queue()
@@ -78,6 +84,9 @@ class _MicroBatcher:
                     batch.append(self.q.get_nowait())
                 except queue.Empty:
                     time.sleep(0.0005)
+            now = time.monotonic()
+            for p in batch:
+                tracing.add(f"{self.name}.wait_s", now - p.enq_t)
             try:
                 self.serve_fn(batch)
             except Exception as e:  # noqa: BLE001 — surface, don't strand

@@ -28,7 +28,7 @@ def test_one_chip_phases_pass_on_cpu(smoke, capsys):
     opts = smoke.parse(["--arch", "qwen3-1.7b-smoke", "--static-rows",
                         "16384", "--capacity", "512", "--nprobe", "256",
                         "--requests", "128"])
-    smoke.run_one_chip(opts, smoke.CompileCounter())
+    smoke.run_one_chip(opts)
     lines = _lines(capsys.readouterr().out)
     assert [ln["phase"] for ln in lines] == ["flat", "ivf", "segmented",
                                              "fused"]
