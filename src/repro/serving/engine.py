@@ -32,6 +32,52 @@ class EngineStats:
     compiles: int = 0
 
 
+class StepTokens:
+    """One step's sampled tokens, ``(rows,)`` int32, left on the device
+    so that the next decode step can take them before the host has read
+    them (``jnp.asarray`` gives the device array, through
+    ``__jax_array__``). To a host reader they are a small numpy array:
+    ``np.asarray``, indexing and ``copy`` work as on one, the first read
+    copies them to the host, and a write (``tok[i] = v``) edits that
+    host copy, which the next step then takes in their place."""
+
+    __slots__ = ("_dev", "_host")
+
+    def __init__(self, dev, host=None):
+        self._dev, self._host = dev, host
+
+    @property
+    def unread(self) -> bool:
+        """No host read has waited for these tokens yet."""
+        return self._host is None
+
+    def _read(self) -> np.ndarray:
+        if self._host is None:
+            self._host = np.asarray(self._dev)
+        return self._host
+
+    def __jax_array__(self):
+        if self._dev is None:
+            self._dev = jnp.asarray(self._host)
+        return self._dev
+
+    def __array__(self, dtype=None, copy=None):
+        host = self._read()
+        return np.array(host, dtype) if copy else np.asarray(host, dtype)
+
+    def __getitem__(self, i):
+        return self._read()[i]
+
+    def __setitem__(self, i, v):
+        host = np.array(self._read())
+        host[i] = v
+        self._dev, self._host = None, host
+
+    def copy(self) -> "StepTokens":
+        # both halves are never written in place: sharing them is a copy
+        return StepTokens(self._dev, self._host)
+
+
 def _bucket(n: int, lo: int = 1) -> int:
     """Smallest power of two >= max(n, lo)."""
     return 1 << (max(n, lo) - 1).bit_length()
@@ -47,11 +93,22 @@ class LLMEngine:
     end that never sends more than ``min_batch`` rows thus runs one
     decode program.
 
+    Decoding is pipelined one step deep: the sampled tokens stay on
+    the device and feed the next step, which is dispatched before the
+    host reads them, so the device runs the steps back to back while
+    the host does its bookkeeping. A batch of ``max_new_tokens`` takes
+    the prefill's token and ``max_new_tokens - 1`` decode steps.
+
     Spans (``repro.tracing``): ``engine.batch`` per call, inside it
-    ``engine.prefill`` (tokenize, prefill, first sample) and one
-    ``engine.decode`` per step, each with ``engine.sample`` (the argmax
-    and its copy to the host) as a child. A decode step whose tokens
-    no row takes counts under ``engine.decode_steps_unserved``."""
+    ``engine.prefill`` (tokenize, prefill, first sample, the dispatch
+    of decode step 1 and the read of the prefill's token) and one
+    ``engine.decode`` per served decode step (the dispatch of the next
+    step and the read of this step's tokens: one device step of wall
+    time), each dispatch with ``engine.sample`` (the argmax, left on
+    the device) as a child. Counters: ``engine.decode_steps_ahead``,
+    steps dispatched before the tokens they consume were read on the
+    host; ``engine.decode_steps_unserved``, steps in flight when every
+    row had ended on EOS."""
 
     def __init__(self, cfg: LMConfig, params=None, seed: int = 0,
                  max_len: int = 256, temperature: float = 0.0,
@@ -86,6 +143,9 @@ class LLMEngine:
     def _generate(self, prompts: List[str], max_new: int) -> List[str]:
         B = len(prompts)
         Bp = _bucket(B, self.min_batch)
+        out = [[] for _ in range(B)]
+        done = np.zeros(Bp, bool)
+        done[B:] = True
         with tracing.span("engine.prefill", rows=B):
             in_len = _bucket(max(len(p.encode()) + 2 for p in prompts), 16)
             in_len = min(in_len, self.max_len - max_new)
@@ -98,36 +158,51 @@ class LLMEngine:
             logits, cache = self._prefill(self.params, jnp.asarray(toks))
             self.stats.prefills += B
             tok = self._sample(logits)
-
-        out = [[] for _ in range(B)]
-        done = np.zeros(Bp, bool)
-        done[B:] = True
-        for _ in range(max_new):
-            for b in range(B):
-                if not done[b]:
-                    out[b].append(int(tok[b]))
-                    done[b] |= int(tok[b]) == EOS
-            if done.all():
-                break
-            with tracing.span("engine.decode", rows=B):
-                logits, cache = self._decode(self.params, cache,
-                                             jnp.asarray(tok))
-                self.stats.decode_steps += 1
-                tok = self._sample(logits)
-        else:
-            if max_new:          # the last step's tokens are never taken
+            if max_new > 1:
+                cache, nxt = self._step(cache, tok)
+            if max_new:
+                self._take(tok, out, done)
+        # token t is served from the step dispatched when token t - 1
+        # was still unread: the device never waits on the host's read
+        for t in range(1, max_new):
+            if done.all():       # the step in flight is not served
                 tracing.add("engine.decode_steps_unserved", 1)
+                break
+            tok = nxt
+            with tracing.span("engine.decode", rows=B):
+                if t + 1 < max_new:
+                    cache, nxt = self._step(cache, tok)
+                self._take(tok, out, done)
         self.stats.generated_tokens += sum(len(o) for o in out)
         self.stats.batches += 1
         return [self.tok.decode(o) for o in out]
 
-    def _sample(self, logits) -> np.ndarray:
+    def _step(self, cache, tok):
+        """Dispatch one decode step on ``tok``, normally before the host
+        has read it; the step's own tokens stay on the device."""
+        if isinstance(tok, StepTokens) and tok.unread:
+            tracing.add("engine.decode_steps_ahead", 1)
+        logits, cache = self._decode(self.params, cache, jnp.asarray(tok))
+        self.stats.decode_steps += 1
+        return cache, self._sample(logits)
+
+    @staticmethod
+    def _take(tok, out, done):
+        """Read a step's tokens on the host and serve them to the rows
+        that have not ended."""
+        tok = np.asarray(tok)
+        for b in range(len(out)):
+            if not done[b]:
+                out[b].append(int(tok[b]))
+                done[b] |= int(tok[b]) == EOS
+
+    def _sample(self, logits) -> StepTokens:
+        """Dispatch the argmax; the tokens stay on the device."""
         with tracing.span("engine.sample", rows=logits.shape[0]):
-            if self.temperature <= 0:
-                return np.asarray(jnp.argmax(logits, -1), np.int32)
-            g = np.random.gumbel(size=logits.shape)
-            return np.asarray(
-                jnp.argmax(logits / self.temperature + g, -1), np.int32)
+            if self.temperature > 0:
+                logits = logits / self.temperature + np.random.gumbel(
+                    size=logits.shape)
+            return StepTokens(jnp.argmax(logits, -1).astype(jnp.int32))
 
     def generate(self, prompt: str, max_new_tokens: int = 32) -> str:
         return self.generate_batch([prompt], max_new_tokens)[0]

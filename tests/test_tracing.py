@@ -219,10 +219,11 @@ def test_engine_decode_spans_equal_decode_steps():
     assert sp["engine.sample"]["calls"] == \
         eng.stats.decode_steps + eng.stats.batches
     assert c["batching-frontend.wait_s"]["n"] == 3
-    # a batch that runs all its steps decodes one whose tokens no row takes
+    # a batch of max_new tokens decodes max_new - 1 steps, each served
+    # and each dispatched before the tokens it consumes were read
     unserved = c.get("engine.decode_steps_unserved", {"n": 0})["n"]
-    assert unserved == 2 if eng.stats.decode_steps == 4 + 3 \
-        else unserved < 2
+    assert eng.stats.decode_steps == 3 + 2 and unserved == 0
+    assert c["engine.decode_steps_ahead"]["n"] == eng.stats.decode_steps
     # named programs: the profiler reads jit_prefill / jit_decode
     assert (eng._prefill.__name__, eng._decode.__name__) == \
         ("prefill", "decode")
