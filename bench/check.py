@@ -1,7 +1,8 @@
 """The comparison that decides ``correct``.
 
 Once the window has closed and the program's device state is freed,
-the reference (``bench/reference.py``) is run over what the timed path
+the reference (the module the configuration names under ``reference``,
+loaded by ``common.reference``) is run over what the timed path
 produced:
 
 - the batches drawn from the seed while the window ran, each with the
@@ -34,7 +35,7 @@ Four numbers, each beside its limit from the configuration's
   below the reference's best at its position.
 
 With ``--control 1`` the control takes the program's place: the
-reference in the next precision down (``reference.CONTROL``) is read
+reference in the next precision down (the module's ``CONTROL``) is read
 over the same requests and tokens, its numbers are held to the same
 limits and decide ``correct``, which must come out false. The
 program's numbers are kept beside them under ``program``.
@@ -47,7 +48,6 @@ from typing import Optional
 import numpy as np
 
 from bench import common
-from bench import reference as R
 
 HITS = ("dynamic", "rewritten")
 EOS, BOS, OFFSET = 2, 1, 3
@@ -70,8 +70,9 @@ def run(conf: dict, dep: dict, mix: dict, tr, got: dict, args) -> dict:
     import time
     t0 = time.monotonic()
     limits = {k: float(v) for k, v in conf["limits"].items()}
+    R = common.reference(conf)
     modes = (R.FP32, R.CONTROL) if args.control else (R.FP32,)
-    ctx = _Context(dep, tr, got, limits)
+    ctx = _Context(R, dep, tr, got, limits)
     nums = {m: {"embed_err": 0.0, "score_err": 0.0, "decision_errors": 0}
             for m in modes}
     counts = {"batches": 0, "requests": 0, "near_tau": 0}
@@ -79,7 +80,7 @@ def run(conf: dict, dep: dict, mix: dict, tr, got: dict, args) -> dict:
         ctx.check_batch(s, modes, nums, counts)
     t_cache = time.monotonic() - t0
     rng = np.random.default_rng([int(args.seed) % 2 ** 63, 2])
-    gaps = _check_backend(dep, got["engine_calls"], rng, modes,
+    gaps = _check_backend(R, dep, got["engine_calls"], rng, modes,
                           int(mix.get("check_backend_rows", 48)),
                           int(args.seed))
     counts["tokens"] = gaps.pop("n", 0)
@@ -103,10 +104,11 @@ def run(conf: dict, dep: dict, mix: dict, tr, got: dict, args) -> dict:
 
 
 class _Context:
-    def __init__(self, dep, tr, got, limits):
+    def __init__(self, R, dep, tr, got, limits):
+        self.R = R
         self.tau = float(dep["tau"])
         self.margin = limits["decision_margin"]
-        self.emb = R.Embedder(d_out=int(dep["embedding_dim"]))
+        self.emb = self.R.Embedder(d_out=int(dep["embedding_dim"]))
         self.static_rows = int(dep["static_rows"])
         self.head = [t for _, t in tr.head]
         self.row_of_cls = {c: k for k, (c, _) in enumerate(tr.head)}
@@ -115,7 +117,7 @@ class _Context:
         self.emb_of = got["emb_of"]
         known = list(self.emb_of.items())
         self.texts = [p for p, _ in known]
-        self.keys = R.normalize(np.stack([v for _, v in known]))
+        self.keys = self.R.normalize(np.stack([v for _, v in known]))
         self.tiers: dict = {}
         self._static: dict = {}
         self._slot_emb: dict = {}
@@ -123,8 +125,8 @@ class _Context:
 
     def tier(self, mode):
         if mode not in self.tiers:
-            self.tiers[mode] = R.static_tier(self.emb(self.head, mode),
-                                             self.static_rows)
+            self.tiers[mode] = self.R.static_tier(
+                self.emb(self.head, mode), self.static_rows)
         return self.tiers[mode]
 
     def answer_static(self, row: int) -> str:
@@ -143,7 +145,7 @@ class _Context:
         where no served embedding matches)."""
         if len(rows) == 0:
             return []
-        best, arg, _ = R.top2(R.normalize(rows), self.keys)
+        best, arg, _ = self.R.top2(self.R.normalize(rows), self.keys)
         return [self.texts[int(a)] if b >= 1.0 - 1e-4 else None
                 for b, a in zip(best, arg)]
 
@@ -151,7 +153,7 @@ class _Context:
         """Static top-1 of every sampled request, in one pass."""
         if mode not in self._static:
             V = self.emb(prompts, mode)
-            self._static[mode] = (V, *R.top2(V, self.tier(mode), mode))
+            self._static[mode] = (V, *self.R.top2(V, self.tier(mode), mode))
         return self._static[mode]
 
     def decide(self, prompts, V, stat, mode, slots, D, texts, new):
@@ -163,7 +165,7 @@ class _Context:
             keep = np.array([int(x) not in gone for x in slots], bool)
             cand = [D[keep]] + ([V[ins]] if ins else [])
             C = np.concatenate(cand) if len(cand) > 1 else cand[0]
-            ds, da, d2 = R.top2_host(V[i:i + 1], C, mode)
+            ds, da, d2 = self.R.top2_host(V[i:i + 1], C, mode)
             j = int(da[0])
             kept = np.nonzero(keep)[0]
             if j < len(kept):
@@ -198,7 +200,7 @@ class _Context:
         n_miss = sum(r[0] == "backend" for r in served)
         counts["batches"] += 1
         counts["requests"] += len(prompts)
-        Vp = R.normalize(np.stack([self.emb_of[p] for p in prompts]))
+        Vp = self.R.normalize(np.stack([self.emb_of[p] for p in prompts]))
         lo = counts["requests"] - len(prompts)
         ref = None
         for m in modes:
@@ -208,7 +210,7 @@ class _Context:
             D = self.slot_emb(texts, m)
             dec = self.decide(prompts, V, (sa_[sl], sb_[sl], sc_[sl]), m,
                               slots, D, texts, new)
-            if m == R.FP32:
+            if m == self.R.FP32:
                 ref, Vref = dec, V
                 nums[m]["embed_err"] = max(nums[m]["embed_err"],
                                            float(np.abs(Vp - Vref).max()))
@@ -223,7 +225,7 @@ class _Context:
                         d.kind == "static" or (
                             d.dyn_slot >= 0 and bool(origin[d.dyn_slot])))
                        for d in dec]
-            self.compare(ref, got, nums[m], counts, m == R.FP32)
+            self.compare(ref, got, nums[m], counts, m == self.R.FP32)
 
     def slot_emb(self, texts, mode):
         """Reference embeddings of tier-row texts, each embedded once."""
@@ -267,7 +269,7 @@ class _Context:
                     nums["decision_errors"] += 1
 
 
-def _check_backend(dep, calls, rng, modes, n_rows: int, seed: int):
+def _check_backend(R, dep, calls, rng, modes, n_rows: int, seed: int):
     """Reference logits over a sample of the served backend rows."""
     be = dep["backend"]
     max_len, max_new = int(be["max_len"]), int(be["max_new_tokens"])

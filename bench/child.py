@@ -346,17 +346,7 @@ def build(dep: dict, tr, args, rec: Recorder) -> dict:
 
     be = dep["backend"]
     lm = lm_config(be["arch"])
-    for key, attr in (("num_hidden_layers", "n_layers"),
-                      ("hidden_size", "d_model"),
-                      ("num_attention_heads", "n_heads"),
-                      ("num_key_value_heads", "n_kv_heads"),
-                      ("intermediate_size", "d_ff"),
-                      ("vocab_size", "vocab_size"),
-                      ("head_dim", "head_dim")):
-        if int(be[key]) != int(getattr(lm, attr)):
-            raise SystemExit(f"child: backend {be['arch']} has {attr}="
-                             f"{getattr(lm, attr)}, the configuration "
-                             f"states {key}={be[key]}")
+    check_backend_keys(be, lm)
     # the weights, drawn from the seed on the device in one jitted call
     params = jax.jit(functools.partial(tr_model.init_params, lm))(
         jax.random.PRNGKey(weight_seed(args.seed)))
@@ -419,7 +409,7 @@ def build(dep: dict, tr, args, rec: Recorder) -> dict:
     def sample_fn(logits):
         tok = sample(logits)
         if args.fault == "token" and len(calls) % 2 == 0:
-            tok = tok.copy()
+            tok = np.array(tok)       # a host copy, served in its place
             tok[0] = (int(tok[0]) + 1) % lm.vocab_size
         calls[-1]["tokens"].append(tok.copy())
         return tok
@@ -434,6 +424,50 @@ def build(dep: dict, tr, args, rec: Recorder) -> dict:
         policy._bulk_insert_fn = lambda dyn, *a, **k: dyn
     svc["watch"] = TierWatch(policy)
     return svc
+
+
+RUN_KEYS = ("arch", "max_len", "max_new_tokens", "batch")
+
+
+def published_keys(lm) -> dict:
+    """The program's backend configuration (an ``LMConfig``) under the
+    names of the published ``config.json``. Where the program provides
+    its own ``repro.configs.published_keys``, ``check_backend_keys``
+    takes that one instead, so that keys of a new architecture are
+    named where the program defines it."""
+    out = {"num_hidden_layers": lm.n_layers, "hidden_size": lm.d_model,
+           "num_attention_heads": lm.n_heads,
+           "num_key_value_heads": lm.n_kv_heads, "head_dim": lm.head_dim,
+           "intermediate_size": lm.d_ff, "vocab_size": lm.vocab_size,
+           "rope_theta": lm.rope_theta, "rms_norm_eps": lm.norm_eps,
+           "qk_norm": lm.qk_norm, "tie_word_embeddings": lm.tie_embeddings,
+           "dtype": lm.dtype}
+    if lm.moe is not None:
+        m = lm.moe
+        out.update(num_experts=m.n_experts, num_experts_per_tok=m.top_k,
+                   moe_intermediate_size=m.d_ff_expert,
+                   n_shared_experts=m.n_shared_experts,
+                   shared_expert_intermediate_size=m.n_shared_experts
+                   * m.d_ff_expert)
+    return out
+
+
+def check_backend_keys(be: dict, lm) -> None:
+    """Stop unless every key of the configuration's ``backend`` block,
+    but the run's own (``RUN_KEYS``), is one the program reports for
+    ``lm``, with an equal value."""
+    import repro.configs
+    have = getattr(repro.configs, "published_keys", published_keys)(lm)
+    for key, want in be.items():
+        if key in RUN_KEYS:
+            continue
+        if key not in have:
+            raise SystemExit(f"child: backend {be['arch']} does not report "
+                             f"{key}, which the configuration states")
+        if have[key] != want:
+            raise SystemExit(f"child: backend {be['arch']} has {key}="
+                             f"{have[key]!r}, the configuration states "
+                             f"{key}={want!r}")
 
 
 def weight_seed(seed: int) -> int:

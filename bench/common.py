@@ -3,6 +3,7 @@ a workload, its configuration and its traffic mix are found by name.
 Standard library only (the parent never imports JAX)."""
 from __future__ import annotations
 
+import importlib.util
 import json
 import math
 from pathlib import Path
@@ -35,6 +36,24 @@ def config(name: str) -> dict:
                 raise SystemExit(f"configuration file {path} is missing")
             return json.loads(path.read_text())
     raise SystemExit(f"no configuration {name!r} in BENCHMARK.json")
+
+
+def reference(conf: dict):
+    """The plain reference module that the configuration names under
+    its ``reference`` key, loaded from that path in the checkout (its
+    contract: ``bench/reference.py``)."""
+    path = CHECKOUT / conf["reference"]
+    if not path.is_file():
+        raise SystemExit(f"reference module {path} is missing")
+    return load(path, f"bench_reference_{path.stem}")
+
+
+def load(path: Path, name: str):
+    """The Python module at ``path``, loaded under ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def in_len(prompt: str, max_len: int, max_new: int) -> int:

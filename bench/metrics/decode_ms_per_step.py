@@ -1,5 +1,7 @@
-"""Backend: host-clock time of one engine decode step, dispatch through
-the sampled tokens' copy to the host (span ``engine.decode``), in ms."""
+"""Backend: host-clock time of one pipelined engine decode step (span
+``engine.decode``), in ms: the dispatch of the next step, then the read
+of this step's tokens, which waits until the device has produced them.
+While the device is the bound, a span lasts about one device step."""
 from bench import program
 
 

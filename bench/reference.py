@@ -1,7 +1,30 @@
-"""The plain reference of both configurations, written from their
-documented semantics. It imports nothing of the program and takes
-nothing the program made: weights are drawn again from the seed, the
-curated tier is embedded again from its texts.
+"""The plain reference of the ``krites-flat`` configuration, written
+from its documented semantics. It imports nothing of the program and
+takes nothing the program made: weights are drawn again from the seed,
+the curated tier is embedded again from its texts.
+
+A configuration names its reference module by path under its
+``reference`` key, and the harness loads it from there
+(``common.reference``). Such a module provides what ``bench/check.py``
+and the metric readers call:
+
+- ``FP32`` and ``CONTROL``, the names of the reference's precision and
+  of the control's, one precision down;
+- ``Embedder``, ``normalize``, ``static_tier``, ``top2`` and
+  ``top2_host``: the embedder, the static tier and the cosine top-1;
+- ``lm_weights(be, seed)``: the backend's weights, drawn from ``seed``
+  for the configuration's ``backend`` block ``be``;
+- ``lm_gaps(be, w, seqs, starts, served, modes)``: per mode, the gap
+  of each served token's logit below the reference's best;
+- ``backend_ops(be, bw)``: the operations the backend's work ``bw``
+  (``bench/child.backend_work``) required, for ``step_mfu``.
+
+It imports JAX only inside functions: the parent process, which never
+imports JAX, loads it for its operation count. A module for another
+backend architecture may ``from bench.reference import *`` and replace
+only the backend functions.
+
+This module's parts:
 
 - the embedder: signed feature hashing of character 2-, 3- and
   4-grams and words (blake2s, 8 bytes), a 1024 -> 256 -> 64 MLP with a
@@ -14,7 +37,9 @@ curated tier is embedded again from its texts.
   SwiGLU, untied unembedding; weights truncated N(0, 1) on [-3, 3]
   scaled by fan_in^-1/2 (the embedding unscaled) and rounded to
   bfloat16, drawn from PRNGKey(seed) in the order of the checkpoint's
-  sorted layer-leaf names.
+  sorted layer-leaf names. Its operations: 2 per weight per token
+  through the layers, 2*d*vocab per logits row, 4*heads*head_dim per
+  (query, key) pair of attention.
 
 Everything computes in float32 (``precision=HIGHEST`` on a TPU). The
 control computes the same in the next precision down: int8 operands in
@@ -218,6 +243,21 @@ def _leaf_shapes(c: dict) -> dict:
               "wu": (d, c["intermediate_size"]),
               "wd": (c["intermediate_size"], d)}
     return shapes
+
+
+def backend_ops(be: dict, bw: dict) -> float:
+    """Operations the backend's work required: ``bw`` counts the rows,
+    the prompt tokens as the engine pads them, the decode tokens and the
+    (query, key) pairs of attention."""
+    d, H, K = be["hidden_size"], be["num_attention_heads"], \
+        be["num_key_value_heads"]
+    hd, ff, L, V = be["head_dim"], be["intermediate_size"], \
+        be["num_hidden_layers"], be["vocab_size"]
+    per_token = L * (d * H * hd + 2 * d * K * hd + H * hd * d + 3 * d * ff)
+    tokens = bw["prefill_tokens"] + bw["decode_tokens"]
+    logits = bw["rows"] + bw["decode_tokens"]
+    return 2.0 * per_token * tokens + 2.0 * d * V * logits \
+        + 4.0 * L * H * hd * bw["attn_pairs"]
 
 
 def lm_weights(c: dict, seed: int) -> dict:

@@ -32,7 +32,6 @@ out not correct.
 from __future__ import annotations
 
 import argparse
-import importlib.util
 import json
 import os
 import subprocess
@@ -255,6 +254,7 @@ def main(argv=None) -> None:
             if child.get("trace_dir") else None
         ctx = {"child": child, "trace": red, "deployment":
                _deployment(conf, args), "workload": wl,
+               "reference": common.reference(conf),
                "peaks": _peaks(device["kind"]), "e2e": e2e}
         metrics = {}
         for m in bm["per_layer"]:
@@ -306,11 +306,7 @@ def _reader(name: str):
     path = BENCH / "metrics" / f"{name}.py"
     if not path.is_file():
         raise SystemExit(f"no reader for metric {name!r} ({path})")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return common.load(path, f"bench_metric_{name}").read
 
 
 def _peaks(kind: str) -> dict:

@@ -1,7 +1,12 @@
 """Operation and byte counts from shapes, against hand-computed ones."""
+import json
+from pathlib import Path
+
 import pytest
 
-from bench import work
+from bench import reference, work
+
+CONFIG = Path(__file__).resolve().parents[1] / "configs" / "krites-flat.json"
 
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
@@ -31,8 +36,17 @@ def test_backend_and_embedder():
     assert per_layer == 50_331_648
     bw = {"rows": 8, "prefill_tokens": 8 * 64, "decode_tokens": 8 * 7,
           "attn_pairs": 1000}
-    ops = work.backend(be, bw)
+    ops = reference.backend_ops(be, bw)
     want = 2 * 28 * per_layer * (512 + 56) + 2 * 2048 * 151936 * (8 + 56) \
         + 4 * 28 * 16 * 128 * 1000
     assert ops == want
     assert work.embed(10) == 2 * 10 * (1024 * 256 + 256 * 64)
+
+
+def test_backend_ops_of_krites_flat_is_pinned():
+    """The count ``step_mfu`` takes for the ``krites-flat`` backend, as
+    the harness has always counted it."""
+    be = json.loads(CONFIG.read_text())["deployment"]["backend"]
+    bw = {"rows": 83, "prefill_tokens": 5312, "decode_tokens": 576,
+          "attn_pairs": 211848}
+    assert reference.backend_ops(be, bw) == 17054461853696.0

@@ -4,9 +4,9 @@ what the algorithm needs, not what an implementation moves.
 - lookups: per batch, the static tier and the dynamic tier each read
   once; 2*d operations per score.
 - embedder: 2*(1024*256 + 256*64) operations per embedded row.
-- backend: 2 operations per weight per token through the layers,
-  2*d*vocab per logits row, 4*heads*head_dim per (query, key) pair of
-  attention.
+
+The backend's count depends on its architecture, so it comes with the
+configuration's reference module (``backend_ops``).
 """
 from __future__ import annotations
 
@@ -26,18 +26,6 @@ def lookup(lk: dict) -> tuple:
 def embed(rows: int, n_features: int = 1024, d: int = 64) -> float:
     h = 4 * d
     return 2.0 * rows * (n_features * h + h * d)
-
-
-def backend(be: dict, bw: dict) -> float:
-    d, H, K = be["hidden_size"], be["num_attention_heads"], \
-        be["num_key_value_heads"]
-    hd, ff, L, V = be["head_dim"], be["intermediate_size"], \
-        be["num_hidden_layers"], be["vocab_size"]
-    per_token = L * (d * H * hd + 2 * d * K * hd + H * hd * d + 3 * d * ff)
-    tokens = bw["prefill_tokens"] + bw["decode_tokens"]
-    logits = bw["rows"] + bw["decode_tokens"]
-    return 2.0 * per_token * tokens + 2.0 * d * V * logits \
-        + 4.0 * L * H * hd * bw["attn_pairs"]
 
 
 def roofline_s(ops: float, byts: float, peaks: dict) -> float:
