@@ -80,6 +80,16 @@ def _masked_dyn_topk(emb, valid, q):
 
 
 @jax.jit
+def _promote_lookup(dyn: T.DynamicTier, q):
+    """A promotion's dedup top-1 over the live tier: the expression of
+    ``tiers.dynamic_lookup`` (masked matmul, argmax) as one compiled
+    program, so the host reads ``(score, slot)`` after one dispatch.
+    It is not ``_masked_dyn_topk``: that program is the serve path's
+    lookup, which promotions leave to the serving batches."""
+    return T.dynamic_lookup(dyn, q)
+
+
+@jax.jit
 def _bulk_insert(dyn: T.DynamicTier, V, slots, rows, ts, cls, exps=None
                  ) -> T.DynamicTier:
     """Scatter a batch's inserts into the tier in one fused update.
@@ -228,7 +238,10 @@ class BaselinePolicy:
         if mesh is None:
             self._touch_many = jax.jit(T.touch_many)
             self._bulk_insert_fn = _bulk_insert
-            self._write_fn = T._write
+            # one compiled row write for promotions and scalar inserts;
+            # the tier is not donated: snapshots, WAL replays and
+            # readers of an earlier tier keep its buffers
+            self._write_fn = jax.jit(T._write)
         else:
             self._init_mesh(d)
 
@@ -524,11 +537,11 @@ class BaselinePolicy:
             exp = self._entry_expiry(prompt, self.t)
             with self.dyn_lock:
                 slot = self._host_lru_slot()
+                # plain scalars and the promotion's argument layout:
+                # both writes run one compiled program
                 self.dyn = self._write_fn(
-                    self.dyn, slot, v,
-                    jnp.int32((meta or {}).get("cls", -1)),
-                    jnp.int32(-1), jnp.asarray(False), self.t,
-                    expires=exp)
+                    self.dyn, slot, v, int((meta or {}).get("cls", -1)),
+                    -1, False, self.t, last_used=self.t, expires=exp)
                 self._mirror_write(slot, self.t, static_origin=False,
                                    expires=exp)
                 if self.dyn_index is not None:
@@ -1331,6 +1344,18 @@ class KritesPolicy(BaselinePolicy):
         make it the coldest entry in the tier and the eviction victim
         of the very next insert under churn.
 
+        Device work, on one device: two compiled programs with the
+        host between them. ``_promote_lookup`` gives the dedup
+        ``(score, slot)``, read back in one ``device_get``; the host
+        applies the ``dup_threshold`` test, the LWW guard, the TTL
+        check, picks the slot and appends the WAL record; then the
+        compiled ``_write_fn`` writes the row. Slot, clocks, class,
+        answer ref and origin go in as plain scalars, so one compiled
+        write serves every promotion. The tier is not donated: a
+        snapshot, a WAL replay or a reader may still hold the tier this
+        write replaces. A mesh runs the sharded twins of both programs;
+        a segmented ``dyn_index`` keeps its own lookup.
+
         Spans: ``promote`` with children ``promote.lock_wait``,
         ``promote.lookup``, ``promote.wal`` and ``promote.write``; a
         landed upsert adds its lag from the pool submit to the counter
@@ -1341,7 +1366,8 @@ class KritesPolicy(BaselinePolicy):
     def _apply_promotion(self, payload: dict, journal: bool) -> None:
         h_idx = payload["h_idx"]
         v = jnp.asarray(payload["v"])
-        enq_t = payload["enq_t"]
+        # a Python int: a numpy clock would key a second compile
+        enq_t = int(payload["enq_t"])
         ja = payload.get("judge_args", {})
         # TTL verdict stamped by _judge_payload (or carried by a WAL
         # record on replay). Expiry anchors at enq_t — it is in the WAL
@@ -1373,12 +1399,15 @@ class KritesPolicy(BaselinePolicy):
             # row-sharded masked scan (§13), fresh write into the tier
             with tracing.span("promote.lookup"):
                 if self.mesh is not None:
-                    sd, jd = self._sh_dyn_fn(self.dyn, v[None])
-                    s_d, j = float(sd[0]), int(jd[0])
+                    sd, jd = jax.device_get(
+                        self._sh_dyn_fn(self.dyn, v[None]))
+                    s_d, j = sd[0], jd[0]
+                elif self.dyn_index is not None:
+                    s_d, j = jax.device_get(T.dynamic_lookup(
+                        self.dyn, v, index=self.dyn_index))
                 else:
-                    s_d, j = T.dynamic_lookup(self.dyn, v,
-                                              index=self.dyn_index)
-                    s_d, j = float(s_d), int(j)
+                    s_d, j = jax.device_get(_promote_lookup(self.dyn, v))
+                s_d, j = float(s_d), int(j)
             dup = s_d >= self.cfg.dup_threshold
             if dup and self._written_at_np[j] > enq_t:
                 return       # LWW: a newer write owns this key
@@ -1400,10 +1429,8 @@ class KritesPolicy(BaselinePolicy):
             with tracing.span("promote.write"):
                 slot = j if dup else self._host_lru_slot()
                 self.dyn = self._write_fn(
-                    self.dyn, slot, v,
-                    jnp.int32(cls), jnp.int32(ref),
-                    jnp.asarray(True), enq_t, last_used=apply_t,
-                    expires=exp)
+                    self.dyn, slot, v, cls, ref, True, enq_t,
+                    last_used=apply_t, expires=exp)
                 self._mirror_write(slot, apply_t, static_origin=True,
                                    written_at=enq_t, expires=exp,
                                    rewritten=rewrite)
