@@ -4,9 +4,9 @@
 
 Registered modules (see each module's docstring for what it reproduces):
 ``table1``, ``fig2``, ``greyzone_roi``, ``latency_async``,
-``verifier_fidelity``, ``kernels``, ``serve_batched``, ``sweep``,
-``ann_index``, ``dyn_index``, ``sharded_serve``, ``load_service``,
-``fused_serve``, ``l1_freshness``, ``adaptive_thresholds``.
+``verifier_fidelity``, ``kernels``, ``sweep``, ``ann_index``,
+``dyn_index``, ``sharded_serve``, ``load_service``, ``fused_serve``,
+``l1_freshness``, ``adaptive_thresholds``.
 
 Prints ``name,us_per_call,derived`` CSV rows (derived = remaining fields
 as compact JSON) and writes results/benchmarks.json. A module that
@@ -36,14 +36,13 @@ def main() -> None:
     from benchmarks import (adaptive_thresholds, ann_index, dyn_index,
                             fig2, fused_serve, greyzone_roi,
                             kernels_bench, l1_freshness, latency_async,
-                            load_service, serve_batched, sharded_serve,
-                            sweep, table1, verifier_fidelity)
+                            load_service, sharded_serve, sweep, table1,
+                            verifier_fidelity)
     modules = {
         "table1": table1, "fig2": fig2, "greyzone_roi": greyzone_roi,
         "latency_async": latency_async,
         "verifier_fidelity": verifier_fidelity,
         "kernels": kernels_bench,
-        "serve_batched": serve_batched,
         "sweep": sweep,
         "ann_index": ann_index,
         "dyn_index": dyn_index,

@@ -4,10 +4,8 @@
 #     bash scripts/ci.sh
 #
 # Mirrors what the README documents: the repo must pass
-# `PYTHONPATH=src python -m pytest -x -q`, the benchmark harness must
-# produce rows end to end (serve_batched is the fastest module, ~30s),
-# and the multi-config sweep path must run a 16-config grid (DESIGN.md
-# §10). The --timeout flag is honored by pytest-timeout when installed
+# `PYTHONPATH=src python -m pytest -x -q`, and the multi-config sweep
+# path must run a 16-config grid (DESIGN.md §10). The --timeout flag is honored by pytest-timeout when installed
 # and by the SIGALRM fallback in tests/conftest.py otherwise, so one
 # wedged test cannot hang CI silently.
 set -euo pipefail
@@ -19,9 +17,6 @@ export JAX_PLATFORMS=cpu
 
 echo "== tier-1 tests (per-test timeout 300s) =="
 python -m pytest -x -q --timeout=300
-
-echo "== benchmark smoke (serve_batched, small scale) =="
-python -m benchmarks.run --scale small --only serve_batched
 
 echo "== sweep smoke (16-config grid, one dispatch) =="
 python -m benchmarks.sweep --configs 16 --no-sequential

@@ -133,35 +133,21 @@ class VerifyAndPromotePool:
 
     # -- producer side (called from the serving path; never blocks) -------
     def submit(self, key: tuple, payload: dict) -> bool:
-        task = VerifyTask(key, payload)
-        with self._lock:
-            self.stats.submitted += 1
-            if key in self._inflight:
-                self.stats.deduped += 1
-                return False
-            if not self._take_token():
-                self.stats.rate_limited += 1
-                return False
-            # [dispatch time, task, outstanding copies]: the reaper
-            # re-dispatches a stuck task to another worker and bumps
-            # the copy count; the key leaves the set when a copy wins
-            # or every copy has terminally failed
-            self._inflight[key] = [time.monotonic(), task, 1]
-        try:
-            self.q.put_nowait(task)
-            return True
-        except queue.Full:
-            with self._lock:
-                self.stats.dropped_full += 1
-                self._inflight.pop(key, None)
-            return False
+        """Submit one task: a :meth:`submit_many` of one. True if it
+        was enqueued."""
+        return self.submit_many([(key, payload)]) == 1
 
     def submit_many(self, items) -> int:
-        """Bulk submit for the batched serving path: one lock acquisition
-        for a whole micro-batch of grey-zone triggers. ``items`` is an
-        iterable of (key, payload); returns the number enqueued. Same
-        dedup / token-bucket / drop-on-full semantics as :meth:`submit`,
-        applied per item in order."""
+        """Submit a serving batch's grey-zone triggers under one lock
+        acquisition. ``items`` is an iterable of (key, payload); returns
+        the number enqueued. Per item, in order: a key already in flight
+        is deduped, an empty token bucket rate-limits, and a full queue
+        drops.
+
+        The ``[dispatch time, task, outstanding copies]`` entry lets the
+        reaper re-dispatch a stuck task to another worker and bump the
+        copy count; the key leaves the in-flight set when a copy wins or
+        every copy has terminally failed."""
         accepted = []
         with self._lock:
             for key, payload in items:

@@ -3,8 +3,7 @@
 The cheapest large win at production repeat rates: a byte-identical (up
 to canonicalization) repeat should not pay the embedder or either
 semantic lookup. This tier fronts ``BaselinePolicy``/``KritesPolicy``
-on both serve paths with an O(1) dict probe keyed by the *canonical
-form* of the prompt:
+with an O(1) dict probe keyed by the *canonical form* of the prompt:
 
     NFC unicode normalization -> casefold -> whitespace collapse
 

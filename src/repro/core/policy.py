@@ -10,35 +10,33 @@ The backend, embedder and judge are injected callables, so the same policy
 fronts an LLM engine, a GNN, or a recsys scorer (DESIGN.md §5). The
 static-tier lookup is likewise injectable: pass ``index=`` (a
 ``FlatIndex`` or — for million-entry tiers — an ``IVFIndex``, DESIGN.md
-§11) and both serving entry points route their static top-1 through it;
-the default (None) stays the exact flat/simsearch path.
+§11) and the serving path routes its static top-1 through it; the
+default (None) stays the exact flat/simsearch path.
 
-Two serving entry points share one decision procedure:
-
-- ``serve(prompt)``        — scalar path, one request at a time;
-- ``serve_batch(prompts)`` — the batched hot path (DESIGN.md §7): embeds
-  the whole micro-batch at once, does ONE fused static-tier lookup via
-  ``kernels/simsearch`` (Pallas on TPU, jnp reference elsewhere) and ONE
-  masked dynamic-tier lookup against the tier snapshot, then resolves rows
-  in request order so results are identical to calling ``serve`` per row.
-  Misses go to the backend as a single batch (amortized prefill),
-  grey-zone triggers are bulk-enqueued to the VerifyAndPromote pool, and
-  all tier mutations land as one fused scatter at the end of the batch.
+One procedure makes every serving decision: ``serve_batch(prompts)``
+(DESIGN.md §7). It embeds the whole micro-batch at once, does ONE fused
+static-tier lookup via ``kernels/simsearch`` (Pallas on TPU, jnp
+reference elsewhere) and ONE masked dynamic-tier lookup against the tier
+snapshot, then resolves rows in request order, so a batch of N equals N
+batches of one. Misses go to the backend as a single batch (amortized
+prefill), grey-zone triggers are bulk-enqueued to the VerifyAndPromote
+pool, and all tier mutations land as one fused scatter at the end of the
+batch. ``serve(prompt)`` is a batch of one.
 
 The policy keeps small host-side mirrors of the dynamic tier's decision
 metadata (valid / last_used / static_origin / written_at) so per-row
 bookkeeping (LRU slot choice, provenance reads, the promotion LWW
 guard) never costs a device round-trip; the functional JAX tier stays
 the source of truth for state that is looked up, checkpointed, or
-sharded. Every mutation path (scalar serve, batch serve, async promote)
-updates both under ``dyn_lock``.
+sharded. Both mutation paths (the serving batch, async promotion)
+update both under ``dyn_lock``.
 
 **Multi-device serving (DESIGN.md §13).** Pass ``mesh=`` and the whole
 serving path becomes mesh-aware: the static top-1 runs row-sharded
 through ``sharded_cosine_topk`` (or inject a ``ShardedIVFIndex`` via
 ``index=`` for the ANN twin), the dynamic lookup through the
 row-sharded masked top-1 with global-slot merge, and every tier write —
-scalar insert, batched ``_bulk_insert``, LRU touches, async promotion —
+batched ``_bulk_insert``, LRU touches, async promotion —
 lands on the owning shard as a shard-local scatter without ever
 gathering the tier. Serving decisions are identical to the
 single-device path on any shard count (test-enforced): scores are
@@ -238,9 +236,9 @@ class BaselinePolicy:
         if mesh is None:
             self._touch_many = jax.jit(T.touch_many)
             self._bulk_insert_fn = _bulk_insert
-            # one compiled row write for promotions and scalar inserts;
-            # the tier is not donated: snapshots, WAL replays and
-            # readers of an earlier tier keep its buffers
+            # one compiled row write for promotions; the tier is not
+            # donated: snapshots, WAL replays and readers of an earlier
+            # tier keep its buffers
             self._write_fn = jax.jit(T._write)
         else:
             self._init_mesh(d)
@@ -336,230 +334,55 @@ class BaselinePolicy:
     # adaptive thresholds (core/adaptive.py, DESIGN.md §17)
     # ------------------------------------------------------------------
 
-    def _live_taus(self, prompt: str, *, locked: bool = False):
+    def _live_taus(self, prompt: str):
         """The (tau_static, tau_dynamic, segment) this request serves
         under: the controller's live per-segment operating point, or
         the pinned cfg values (segment −1) without a controller.
-        Segment classification is pure text work and runs outside any
-        lock; the threshold pair is read under ``dyn_lock`` — the one
-        source of truth every serving path (scalar, batch, fused, mesh)
-        shares with the controller's adaptation writes."""
+        Called under ``dyn_lock``: the threshold pair is read there,
+        the one source of truth the serving batch shares with the
+        controller's adaptation writes."""
         if self.adaptive is None:
             return self.cfg.tau_static, self.cfg.tau_dynamic, -1
         seg = A.segment_of(prompt)
-        if locked:
-            return (self.adaptive.tau_static[seg],
-                    self.adaptive.tau_dynamic[seg], seg)
-        with self.dyn_lock:
-            return (self.adaptive.tau_static[seg],
-                    self.adaptive.tau_dynamic[seg], seg)
+        return (self.adaptive.tau_static[seg],
+                self.adaptive.tau_dynamic[seg], seg)
 
-    def _adapt_record(self, v_np, meta, h_idx, seg, res,
-                      *, locked: bool = False) -> None:
-        """Append a served semantic request to the controller window.
-        The outcome label starts as the caller-declared class
-        (``meta['cls']``), falling back to the static neighbor's class;
-        judge verdicts / error feedback rewrite it later via the seq
-        stamped into ``res.meta['adapt_seq']``."""
-        if self.adaptive is None or seg < 0:
-            return
+    def _adapt_record(self, v_np, meta, h_idx, seg, res) -> None:
+        """Append a served semantic request of segment ``seg`` to the
+        controller window. Called under ``dyn_lock``, once the batch's
+        backend call has returned: a failed batch served nobody and
+        records nothing. The outcome label starts as the
+        caller-declared class (``meta['cls']``), falling back to the
+        static neighbor's class; judge verdicts / error feedback
+        rewrite it later via the seq stamped into
+        ``res.meta['adapt_seq']``."""
         label = int((meta or {}).get("cls", -1))
         if label < 0:
             label = int(self._static_cls_np[h_idx])
-        if locked:
-            seq = self.adaptive.record(v_np, label, seg)
-        else:
-            with self.dyn_lock:
-                seq = self.adaptive.record(v_np, label, seg)
-        res.meta["adapt_seq"] = seq
+        res.meta["adapt_seq"] = self.adaptive.record(v_np, label, seg)
         res.meta["segment"] = seg
 
     def _maybe_adapt(self) -> None:
         """Serve-call-boundary adaptation check. Must be called with
         ``dyn_lock`` released — the controller snapshots and installs
-        under the lock itself and runs the shadow sweep outside it. The
-        scalar path checks after every request (the reference twin's
-        cadence); the batched path checks once per batch, so a batch
-        may overshoot ``adapt_every`` by up to B−1 records — the same
-        deliberate batching relaxation as the L1 write-back order."""
+        under the lock itself and runs the shadow sweep outside it. It
+        runs once per :meth:`serve_batch` call: a batch of one (every
+        :meth:`serve`) checks after each request, the reference twin's
+        cadence; a batch of N may overshoot ``adapt_every`` by up to
+        N−1 records — the same deliberate batching relaxation as the L1
+        write-back order."""
         if self.adaptive is not None:
             self.adaptive.maybe_adapt(self.dyn_lock, self.static.emb,
                                       self.static.cls)
 
-    # -- hooks for Krites (no-ops in the baseline) -------------------------
-    def _after_static_miss(self, prompt, v, h_idx, s_static, res, meta,
-                           tau_s=None):
-        return
-
+    # -- hook for Krites (a no-op in the baseline) -------------------------
     def _after_static_miss_batch(self, rows) -> None:
         return
 
     def serve(self, prompt: str, meta: Optional[dict] = None) -> ServeResult:
-        """Scalar serving entry. With the freshness subsystem wired
-        (DESIGN.md §16) the decision procedure gains two stages strictly
-        in FRONT of the classic semantic path:
-
-        1. volatile bypass — a volatile-classified query (with
-           ``volatile_bypass``) goes straight to the backend: no L1
-           read/write, no embed, no tier lookup, no write-back, no
-           grey-zone trigger;
-        2. L1 probe — an exact-match hit on the canonical prompt serves
-           in O(1), skipping the embedder and BOTH semantic lookups.
-
-        Every non-bypassed serve outcome is written back to L1 with its
-        freshness-class expiry, so byte-identical repeats short-circuit
-        next time. Semantic decisions for L1 misses are unchanged.
-        """
-        t0 = time.monotonic()
-        self.t += 1
-        volatile = self._is_volatile(prompt)
-        if volatile and self.freshness.volatile_bypass:
-            self._l1_bypass += 1
-            answer = self.backend_fn(prompt)
-            res = ServeResult(answer, "backend", False, 0.0,
-                              time.monotonic() - t0,
-                              meta={"bypass": "volatile"})
-            self.events.append((res.served_by, res.static_origin))
-            self._maybe_adapt()
-            return res
-        key = None
-        if self.l1 is not None:
-            key = canonicalize(prompt)
-            e = self.l1.get(key, self.t)
-            if e is not None:
-                self._l1_hits += 1
-                res = ServeResult(e.answer, "l1", e.static_origin, 1.0,
-                                  time.monotonic() - t0)
-                self._mark_stale(res, volatile, e.content_t, self.t)
-                self.events.append((res.served_by, res.static_origin))
-                self._maybe_adapt()
-                return res
-        res, content_t = self._serve_semantic(prompt, meta, t0)
-        self._mark_stale(res, volatile, content_t, self.t)
-        if self.l1 is not None:
-            self.l1.put(key, res.answer,
-                        static_origin=res.static_origin,
-                        content_t=content_t,
-                        expires_at=self._entry_expiry(prompt, self.t),
-                        now=self.t)
-        self._maybe_adapt()
-        return res
-
-    def _serve_semantic(self, prompt: str, meta: Optional[dict],
-                        t0: float):
-        """The classic (Alg. 1) decision procedure for one request at
-        tick ``self.t`` (already advanced by the caller). Returns
-        ``(ServeResult, content_t)`` — the content clock is what the
-        served answer's generation time is for drift accounting: 0 for
-        curated static answers, the entry's ``written_at`` for dynamic
-        hits, the current tick for fresh backend answers."""
-        v = l2_normalize(jnp.asarray(self.embed_fn(prompt), jnp.float32))
-        if not _usable_rows(np.asarray(v)[None])[0]:
-            # degenerate embedding (zero / non-finite): serve via the
-            # backend without caching — inserting it would poison the
-            # tier's argmax for every later request — and without a
-            # grey trigger (a promotion would insert the same key)
-            answer = self.backend_fn(prompt)
-            res = ServeResult(answer, "backend", False, 0.0,
-                              time.monotonic() - t0)
-            self.events.append((res.served_by, res.static_origin))
-            return res, self.t
-        tau_s, tau_d, seg = self._live_taus(prompt)
-        content_t = self.t        # backend answers are generated now
-        if self.fused is not None:
-            # fused fast path (DESIGN.md §15): BOTH tier lookups in one
-            # dispatch, under the lock so the touch below lands on the
-            # very tier snapshot the lookup scanned
-            with self.dyn_lock:
-                self._sweep_expired_locked(self.t)
-                ssb, hib, sdb, jdb = jax.device_get(
-                    T.serve_lookup_batch(self.static, self.dyn, v[None],
-                                         self.fused))
-                s_s, h_idx = float(ssb[0]), int(hib[0])
-                s_d, j = float(sdb[0]), int(jdb[0])
-                res = None
-                if s_s < tau_s and s_d >= tau_d:
-                    self.dyn = T.touch(self.dyn, j, self.t)
-                    self._last_used_np[j] = self.t
-                    content_t = int(self._written_at_np[j])
-                    by = "rewritten" if self._rewritten_np[j] \
-                        else "dynamic"
-                    res = ServeResult(self.dyn_answers[j], by,
-                                      bool(self._static_origin_np[j]),
-                                      s_d, time.monotonic() - t0)
-            if s_s >= tau_s:
-                res = ServeResult(self._serve_static(h_idx), "static",
-                                  True, s_s, time.monotonic() - t0)
-                self._adapt_record(np.asarray(v), meta, h_idx, seg, res)
-                self.events.append((res.served_by, res.static_origin))
-                return res, 0
-        else:
-            if self.index is not None:
-                sv, si = self.index.topk(v[None], 1)
-                s_s, h_idx = sv[0, 0], si[0, 0]
-            elif self.mesh is not None:
-                sv, si = self._sh_static_fn(self._static_mesh_tier,
-                                            v[None])
-                s_s, h_idx = sv[0], si[0]
-            else:
-                s_s, h_idx = T.static_lookup(self.static, v)
-            s_s, h_idx = float(s_s), int(h_idx)
-            if s_s >= tau_s:
-                res = ServeResult(self._serve_static(h_idx), "static",
-                                  True, s_s, time.monotonic() - t0)
-                self._adapt_record(np.asarray(v), meta, h_idx, seg, res)
-                self.events.append((res.served_by, res.static_origin))
-                return res, 0
-
-            with self.dyn_lock:
-                self._sweep_expired_locked(self.t)
-                sd, jd = self._dyn_topk(self.dyn, v[None])
-                s_d, j = float(sd[0]), int(jd[0])
-                if s_d >= tau_d:
-                    if self.mesh is None:
-                        self.dyn = T.touch(self.dyn, j, self.t)
-                    else:   # owner-local scatter, batch-shaped
-                        self.dyn = self._touch_many(
-                            self.dyn, np.asarray([j]),
-                            np.asarray([self.t]))
-                    self._last_used_np[j] = self.t
-                    content_t = int(self._written_at_np[j])
-                    by = "rewritten" if self._rewritten_np[j] \
-                        else "dynamic"
-                    res = ServeResult(self.dyn_answers[j], by,
-                                      bool(self._static_origin_np[j]),
-                                      s_d, time.monotonic() - t0)
-                else:
-                    res = None
-
-        if res is None:
-            answer = self.backend_fn(prompt)   # outside the lock
-            exp = self._entry_expiry(prompt, self.t)
-            with self.dyn_lock:
-                slot = self._host_lru_slot()
-                # plain scalars and the promotion's argument layout:
-                # both writes run one compiled program
-                self.dyn = self._write_fn(
-                    self.dyn, slot, v, int((meta or {}).get("cls", -1)),
-                    -1, False, self.t, last_used=self.t, expires=exp)
-                self._mirror_write(slot, self.t, static_origin=False,
-                                   expires=exp)
-                if self.dyn_index is not None:
-                    self.dyn_index.record_write(slot, np.asarray(v))
-                self.dyn_answers[slot] = answer
-            content_t = self.t
-            res = ServeResult(answer, "backend", False, s_d,
-                              time.monotonic() - t0)
-
-        self._adapt_record(np.asarray(v), meta, h_idx, seg, res)
-        self.events.append((res.served_by, res.static_origin))
-        # Alg. 2 line 13: grey-zone test on EVERY static miss (dyn hit or
-        # backend call alike); non-blocking, off the critical path.
-        # The gate uses the SAME live tau_static that made this serving
-        # decision — a concurrent adaptation must not widen/narrow the
-        # grey zone out from under a decision already taken.
-        self._after_static_miss(prompt, v, h_idx, s_s, res, meta, tau_s)
-        return res, content_t
+        """Serve one request: a batch of one through :meth:`serve_batch`,
+        the one implementation of the decision procedure."""
+        return self.serve_batch([prompt], [meta])[0]
 
     def _mirror_write(self, slot: int, now: int, static_origin: bool,
                       written_at: Optional[int] = None,
@@ -584,32 +407,40 @@ class BaselinePolicy:
     # freshness layer (DESIGN.md §16)
     # ------------------------------------------------------------------
 
-    def _sweep_expired_locked(self, now: int) -> int:
-        """Eagerly invalidate dynamic-tier entries past their
-        ``expires_at`` (expired iff ``now > expires_at > 0``). Called
-        under ``dyn_lock`` at the head of every serve/promote critical
-        section, so lookups never see an expired row — the host twin of
-        ``tiers.evict_expired(tier, now)``. Tombstones any injected
-        dynamic index (the one mutation it can't observe through
-        ``record_write``). Returns how many entries died."""
+    def _expire_mirrors(self, now: int) -> np.ndarray:
+        """Flip the host mirrors of the dynamic-tier entries expired at
+        tick ``now`` (expired iff ``now > expires_at > 0``): valid,
+        expiry and rewrite bits, the answers, the eviction counter, and
+        a tombstone in any injected dynamic index (the one mutation it
+        can't observe through ``record_write``). Called under
+        ``dyn_lock``. Returns the expired slots; the caller owns their
+        device scatter."""
         if not self._ttl_active:
-            return 0
+            return np.zeros(0, np.int64)
         dead = np.nonzero(self._valid_np & (self._expires_np > 0)
                           & (self._expires_np < now))[0]
-        if len(dead) == 0:
-            return 0
         self._valid_np[dead] = False
         self._expires_np[dead] = 0
         self._rewritten_np[dead] = False
-        idx = jnp.asarray(dead)
-        self.dyn = self.dyn._replace(
-            valid=self.dyn.valid.at[idx].set(False),
-            expires_at=self.dyn.expires_at.at[idx].set(0))
         for s in dead:
             if self.dyn_index is not None:
                 self.dyn_index.invalidate(int(s))
             self.dyn_answers[int(s)] = None
         self._ttl_evictions += len(dead)
+        return dead
+
+    def _sweep_expired_locked(self, now: int) -> int:
+        """Eagerly invalidate dynamic-tier entries past their
+        ``expires_at``, mirrors and device tier at once — the host twin
+        of ``tiers.evict_expired(tier, now)``. Called under ``dyn_lock``
+        at the head of a promotion and by a restore, so neither sees an
+        expired row. Returns how many entries died."""
+        dead = self._expire_mirrors(now)
+        if len(dead):
+            idx = jnp.asarray(dead)
+            self.dyn = self.dyn._replace(
+                valid=self.dyn.valid.at[idx].set(False),
+                expires_at=self.dyn.expires_at.at[idx].set(0))
         return len(dead)
 
     def _is_volatile(self, prompt: str) -> bool:
@@ -666,10 +497,12 @@ class BaselinePolicy:
     def serve_batch(self, prompts: Sequence[str],
                     metas: Optional[Sequence[Optional[dict]]] = None
                     ) -> List[ServeResult]:
-        """Serve a micro-batch. Equivalent, request for request, to
-        calling :meth:`serve` on each prompt in order (same answers,
-        served_by, static_origin and promotions); the fast primitives are
-        batched instead of per-row.
+        """Serve a micro-batch: the one implementation of the serving
+        decision (Algorithms 1 and 2). A batch of N equals, request for
+        request, N batches of one (same answers, served_by,
+        static_origin and promotions), and the baseline's decisions
+        equal the numpy oracle's (``tests/ref_policy.py``); the fast
+        primitives are batched instead of per-row.
 
         The dynamic-tier lock is held for the whole batch (backend call
         included), so concurrent promotions land between batches — they
@@ -679,17 +512,17 @@ class BaselinePolicy:
         If the batched backend call raises, the batch's inserts are
         rolled back (no answerless cache entries) and the exception
         propagates; hits decided before the failure keep their LRU
-        touches, mirroring the scalar path's failure behavior.
+        touches.
 
         Freshness front (DESIGN.md §16): volatile-bypass rows and L1
         exact-match hits are resolved BEFORE the embedder runs — only
         the remaining rows are embedded and looked up, so a pure-repeat
         batch costs zero embed calls and zero tier dispatches. Ticks
         are assigned to every row (front-resolved or not) in request
-        order, so decisions equal the scalar path's. One deliberate
-        relaxation: L1 write-backs land at the end of the batch, so
-        under L1 *capacity pressure within a single batch* the LRU
-        eviction order can differ from scalar serving (the semantic
+        order, so decisions equal those of batches of one. One
+        deliberate relaxation: L1 write-backs land at the end of the
+        batch, so under L1 *capacity pressure within a single batch* the
+        LRU eviction order can differ from batches of one (the semantic
         decisions never do).
 
         Each call is one serve batch to ``repro.tracing``: the span
@@ -750,11 +583,10 @@ class BaselinePolicy:
                 V = self._embed_batch([prompts[i] for i in sem])  # (Bs, d)
                 if Bp != Bs:
                     V = jnp.pad(V, ((0, Bp - Bs), (0, 0)))
-                # degenerate-embedding guard (same contract as the
-                # scalar path): zero out unusable rows so one NaN can't
-                # leak through the fused lookups, and serve them
-                # backend-only further down — never cached, never
-                # grey-triggered
+                # degenerate-embedding guard: zero out unusable rows so
+                # one NaN can't leak through the fused lookups, and serve
+                # them backend-only further down — never cached (the key
+                # would poison the tier's argmax), never grey-triggered
                 ok = _usable_rows(np.asarray(V)[:Bs])
                 if not ok.all():
                     V = jnp.where(
@@ -803,6 +635,8 @@ class BaselinePolicy:
             backend_rows: List[int] = []
             backend_slots: List[int] = []
             deferred = []        # (row, producer row)
+            adapt_rows = []      # (row, pos, h_idx, segment), recorded
+                                 # once the batch has served
 
             for i in range(B):
                 self.t += 1
@@ -853,37 +687,24 @@ class BaselinePolicy:
                     self.events.append(("backend", False))
                     continue
                 ss_i, h_i = float(s_sb[pos]), int(h_idxb[pos])
-                tau_si, tau_di, seg_i = self._live_taus(prompts[i],
-                                                        locked=True)
+                tau_si, tau_di, seg_i = self._live_taus(prompts[i])
+                if seg_i >= 0:
+                    adapt_rows.append((i, pos, h_i, seg_i))
                 if ss_i >= tau_si:
                     results[i] = ServeResult(self._serve_static(h_i),
                                              "static", True, ss_i, 0.0)
                     content_of[i] = 0
-                    self._adapt_record(V_np[pos], metas[i], h_i, seg_i,
-                                       results[i], locked=True)
                     self._mark_stale(results[i], vol[i], 0, ti)
                     self.events.append(("static", True))
                     continue
 
-                # eager TTL expiry at this row's tick (the batched twin
-                # of the scalar path's pre-lookup sweep): mirrors flip
-                # now; the device scatter is deferred to batch end
-                if self._ttl_active:
-                    newly = np.nonzero(
-                        self._valid_np & (self._expires_np > 0)
-                        & (self._expires_np < ti))[0]
-                    for s in newly:
-                        s = int(s)
-                        self._valid_np[s] = False
-                        self._expires_np[s] = 0
-                        self._rewritten_np[s] = False
-                        if self.dyn_index is not None:
-                            self.dyn_index.invalidate(s)
-                        self.dyn_answers[s] = None
-                        written.pop(s, None)
-                        dead.add(s)
-                        excl.add(s)
-                    self._ttl_evictions += len(newly)
+                # eager TTL expiry at this row's tick: mirrors flip now;
+                # the device scatter is deferred to batch end
+                for s in self._expire_mirrors(ti):
+                    s = int(s)
+                    written.pop(s, None)
+                    dead.add(s)
+                    excl.add(s)
 
                 # dynamic candidate = snapshot best, repaired for slots
                 # overwritten/expired this batch, merged with intra-batch
@@ -940,15 +761,12 @@ class BaselinePolicy:
                                              0.0)
                     content_of[i] = ti
                     self.events.append(("backend", False))
-                self._adapt_record(V_np[pos], metas[i], h_i, seg_i,
-                                   results[i], locked=True)
                 grey_rows.append((prompts[i], V_np[pos], h_i, ss_i,
                                   results[i], metas[i], ti, tau_si))
 
-            # backend first: a failed batch must not commit its inserts
-            # (the scalar path likewise only inserts after the backend
-            # returns), so a backend outage can't poison the cache with
-            # answerless entries
+            # backend first: a failed batch must not commit its inserts,
+            # so a backend outage can't poison the cache with answerless
+            # entries
             answers: List[object] = []
             if backend_rows:
                 try:
@@ -969,6 +787,9 @@ class BaselinePolicy:
                     self._apply_batch_writes(V, {}, touched, Bp,
                                              dead=dead)
                     raise
+            for i, pos, h_i, seg_i in adapt_rows:
+                self._adapt_record(V_np[pos], metas[i], h_i, seg_i,
+                                   results[i])
             with tracing.span("policy.writes", rows=len(w_meta)):
                 self._apply_batch_writes(V, w_meta, touched, Bp,
                                          dead=dead)
@@ -1303,13 +1124,6 @@ class KritesPolicy(BaselinePolicy):
                 "answer": "" if answer is None else str(answer),
             },
         })
-
-    def _after_static_miss(self, prompt, v, h_idx, s_static, res, meta,
-                           tau_s=None):
-        sub = self._grey_submission(prompt, v, h_idx, s_static, res, meta,
-                                    self.t, tau_s)
-        if sub is not None:
-            self.pool.submit(*sub)
 
     def _after_static_miss_batch(self, rows) -> None:
         items = []

@@ -123,10 +123,9 @@ def _owned_slots(slots, axis: str, rows_per: int):
 def sharded_dyn_write(tier, slot, q, cls, answer_ref, static_origin, now,
                       mesh, axis: str = "model", last_used=None,
                       expires=0):
-    """Shard-routed twin of ``tiers._write``: one slot write (scalar
-    serve-path insert / async promotion) landing only on the owning
-    shard. All operands are replicated scalars except the tier itself;
-    no collective runs. Like the single-device twin, ``now`` stamps
+    """Shard-routed twin of ``tiers._write``: one slot write (an async
+    promotion) landing only on the owning shard. All operands are
+    replicated scalars except the tier itself; no collective runs. Like the single-device twin, ``now`` stamps
     ``written_at`` (the LWW clock — enqueue time for promotions) and
     ``last_used`` defaults to it unless the caller passes the live
     clock so a delayed promotion lands LRU-warm."""
@@ -427,7 +426,7 @@ class ShardedIVFIndex:
     merge on a device mesh (DESIGN.md §13).
 
     Drop it into ``BaselinePolicy``/``KritesPolicy`` via ``index=`` and
-    both serving entry points route their static top-1 through
+    the serving path routes its static top-1 through
     :func:`sharded_ivf_topk` with no further policy changes. The corpus
     is padded to a shard multiple with copies of row 0
     (:func:`pad_rows`) whose layout entries are then tombstoned

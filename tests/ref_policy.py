@@ -463,8 +463,8 @@ def ref_simulate(static_emb, static_cls, q_emb, q_cls, cfg, krites,
 
 def ref_adaptive(static_emb, static_cls, q_emb, q_label, q_seg, cfg,
                  params=None, feedback=None) -> dict:
-    """Numpy twin of ``BaselinePolicy`` + ``AdaptiveController`` on the
-    scalar serving path (the oracle for DESIGN.md §17).
+    """Numpy twin of ``BaselinePolicy`` + ``AdaptiveController`` served
+    one request at a time (the oracle for DESIGN.md §17).
 
     One imperative loop per request: serve under the *live per-segment*
     thresholds, record (embedding, label, segment) into the bounded

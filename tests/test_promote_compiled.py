@@ -147,9 +147,10 @@ def test_compiled_promotion_matches_eager_sequence(case):
 
 
 def test_promotion_programs_compile_once():
-    """Varied slots, enqueue and live clocks, expiries and outcomes,
-    and a scalar serve's insert, all reuse one compiled lookup and one
-    compiled write: none of these values keys a new compile."""
+    """Varied slots, enqueue and live clocks, expiries and outcomes all
+    reuse one compiled lookup and one compiled write: none of these
+    values keys a new compile, and a served miss (its insert is the
+    serving batch's scatter) adds no compile of either."""
     jax.clear_caches()
     pol = _seeded()
     payloads = [{"v": V_A, "h_idx": 1, "enq_t": 13},

@@ -1,6 +1,8 @@
-"""Batched serving path: ``serve_batch`` must equal the scalar ``serve``
-path request-for-request (same answers, served_by, static_origin, same
-promotions), and the router must preserve it under concurrency."""
+"""The serving decision procedure, ``serve_batch``: a batch of N must
+equal N batches of one (``serve`` is a batch of one) request for request
+(same answers, served_by, static_origin, same promotions), the baseline
+must equal the numpy oracle ``ref_policy.ref_simulate`` at any batch
+split, and the router must preserve it under concurrency."""
 import dataclasses
 import threading
 
@@ -8,19 +10,24 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ref_policy import (DYN_HIT_DYNAMIC, DYN_HIT_PROMOTED, MISS,
+                        STATIC_HIT, ref_simulate)
+
+from repro.core.adaptive import AdaptiveController, AdaptiveParams
 from repro.core.judge import OracleJudge
 from repro.core.policy import BaselinePolicy, KritesPolicy
 from repro.core.tiers import CacheConfig, make_static_tier
 from repro.data.synth_traces import LMARENA_LIKE, build_benchmark
+from repro.index.flat import l2_normalize
 from repro.serving.router import CacheRouter
 
 N = 500
-BATCH = 32
+BATCH = 8
 
 
 def _trace_setup(n=N, capacity=128):
     """Synthetic trace as a live-policy workload: prompt 'q<i>' embeds to
-    eval row i, so embeddings are identical across both serving paths."""
+    eval row i, so embeddings are identical at every batch split."""
     spec = dataclasses.replace(LMARENA_LIKE, n_requests=4000,
                                n_classes=120)
     bench = build_benchmark(spec)
@@ -49,7 +56,9 @@ def _trace_setup(n=N, capacity=128):
     return dict(cfg=cfg, tier=tier, answers=answers, d=d,
                 prompts=prompts, metas=metas, embed_fn=embed_fn,
                 embed_batch_fn=embed_batch_fn, backend_fn=backend_fn,
-                backend_batch_fn=backend_batch_fn)
+                backend_batch_fn=backend_batch_fn,
+                q_emb=bench.eval_emb[:n], q_cls=bench.eval_cls[:n],
+                static_cls=bench.static_cls)
 
 
 def _assert_rows_equal(scalar, batched):
@@ -62,7 +71,55 @@ def _assert_rows_equal(scalar, batched):
             or abs(a.similarity - b.similarity) < 1e-5, i
 
 
+def _split(n, sizes):
+    """Consecutive [lo, hi) slices of range(n), cycling through
+    ``sizes``."""
+    out, lo, k = [], 0, 0
+    while lo < n:
+        hi = min(n, lo + sizes[k % len(sizes)])
+        out.append((lo, hi))
+        lo, k = hi, k + 1
+    return out
+
+
+_REF_CODE = {"static": STATIC_HIT, "backend": MISS}
+
+
+@pytest.mark.parametrize("sizes", [(1,), (8,), (3, 5, 7, 1)],
+                         ids=["batch1", "batch8", "ragged"])
+def test_serve_batch_matches_reference_baseline(sizes):
+    """Algorithm 1 through the production path equals the numpy oracle
+    row for row, whatever the batch split. The trace keeps every score
+    more than 1e-5 from its threshold, so device and numpy matmuls
+    cannot disagree on a decision."""
+    s = _trace_setup()
+    cfg = s["cfg"]
+    pol = BaselinePolicy(cfg, s["tier"], s["answers"], s["embed_fn"],
+                         s["backend_fn"], d=s["d"],
+                         embed_batch_fn=s["embed_batch_fn"],
+                         backend_batch_fn=s["backend_batch_fn"])
+    out = []
+    for lo, hi in _split(N, sizes):
+        out += pol.serve_batch(s["prompts"][lo:hi], s["metas"][lo:hi])
+    q = np.asarray(l2_normalize(jnp.asarray(s["q_emb"], jnp.float32)))
+    s_emb = np.asarray(s["tier"].emb)
+    s_static = (q @ s_emb.T).max(axis=1)
+    assert np.all(np.abs(s_static - cfg.tau_static) > 1e-5)
+    s_dyn = np.asarray([r.similarity for r in out
+                        if r.served_by != "static"])
+    assert np.all(np.abs(s_dyn - cfg.tau_dynamic) > 1e-5)
+    ref = ref_simulate(s_emb, s["static_cls"], q, s["q_cls"], cfg,
+                       krites=False, capacity=128)
+    for i, r in enumerate(out):
+        want = _REF_CODE.get(r.served_by, DYN_HIT_PROMOTED
+                             if r.static_origin else DYN_HIT_DYNAMIC)
+        assert want == int(ref["served_by"][i]), i
+        assert r.static_origin == bool(ref["static_origin"][i]), i
+    assert {r.served_by for r in out} == {"static", "dynamic", "backend"}
+
+
 def test_serve_batch_matches_scalar_baseline():
+    """Batches of 8 equal batches of one (``serve``)."""
     s = _trace_setup()
     mk = lambda: BaselinePolicy(  # noqa: E731
         s["cfg"], s["tier"], s["answers"], s["embed_fn"], s["backend_fn"],
@@ -131,9 +188,10 @@ def _run_krites_batched(s, judge):
 
 
 def test_serve_batch_matches_scalar_krites_with_promotions():
-    """Full Alg. 2 equivalence: promotions land at the same batch
-    boundaries in both paths, so every decision — including dynamic hits
-    on promoted entries — must match request for request."""
+    """Full Alg. 2 equivalence of batches of 8 and batches of one
+    (``serve``): promotions land at the same batch boundaries in both
+    runs, so every decision — including dynamic hits on promoted
+    entries — must match request for request."""
     s = _trace_setup()
     pol_s, scalar = _run_krites_scalar(s, _GatedOracle())
     pol_b, batched = _run_krites_batched(s, _GatedOracle())
@@ -223,6 +281,38 @@ def test_backend_failure_rolls_back_inserts():
     r = pol.serve_batch([novel])[0]
     assert r.served_by == "backend"
     assert r.answer == f"gen({novel})"
+
+
+def test_backend_failure_records_no_adaptive_evidence():
+    """A failed batch served nobody, so the threshold controller's
+    window gains no row from it — not even from the batch's static
+    hits; the retry records every row."""
+    s = _trace_setup()
+    state = {"fail": True}
+
+    def flaky_batch(ps):
+        if state["fail"]:
+            raise RuntimeError("backend down")
+        return [f"gen({p})" for p in ps]
+
+    ctl = AdaptiveController(s["cfg"], d=s["d"],
+                             params=AdaptiveParams(window=64))
+    pol = BaselinePolicy(s["cfg"], s["tier"], s["answers"], s["embed_fn"],
+                         s["backend_fn"], d=s["d"],
+                         backend_batch_fn=flaky_batch, adaptive=ctl)
+    probe = BaselinePolicy(s["cfg"], s["tier"], s["answers"],
+                           s["embed_fn"], s["backend_fn"], d=s["d"])
+    hit = next(p for p, m in zip(s["prompts"], s["metas"])
+               if probe.serve(p, m).served_by == "static")
+    batch = [hit, _find_novel(s)]
+    with pytest.raises(RuntimeError):
+        pol.serve_batch(batch)
+    assert pol.stats()["adaptive_window_fill"] == 0
+    state["fail"] = False
+    out = pol.serve_batch(batch)
+    assert [r.served_by for r in out] == ["static", "backend"]
+    assert [r.meta["adapt_seq"] for r in out] == [1, 2]
+    assert pol.stats()["adaptive_window_fill"] == 2
 
 
 def test_router_surfaces_backend_errors():
